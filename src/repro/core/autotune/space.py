@@ -256,7 +256,8 @@ def _pa_vmem(shapes, cfg, dtype):
     # in flight while page j is consumed), never the staged pool.  The
     # split form keeps the same two-slot scratch PER CELL; what grows
     # with num_splits is the partial-row buffer the merge pass reads.
-    kv = 2 * 2 * bs * D * it               # 2 K-page + 2 V-page slots
+    # A slot holds the page's KV heads (the DMA moves whole pages).
+    kv = 2 * 2 * bs * shapes["kv_heads"] * D * it   # 2 K + 2 V page slots
     q_o = D * (4 + it)                     # q in f32 + output row
     state = (D + 2) * 4                    # acc + (m, l), f32
     scores = bs * 4                        # s/p transient

@@ -732,11 +732,15 @@ def run_traffic_scaling_cell(params: Dict[str, Any], quick: bool = False
     period = max(r, 2)
 
     def build_cluster(policy):
+        # simulated replicas: all on this host's default device, stepped
+        # on one SimClock (``ServingCluster.build`` needs a device each)
         clock = SimClock()
-        cl = ServingCluster.build(
-            model, weights, n_replicas=r, policy=policy, clock=clock,
-            cost_model=cm, max_batch=max_batch, max_len=max_len,
-            block_size=bs, n_blocks=n_blocks, chunk_size=chunk,
+        replicas = [PagedServingEngine(
+            model, weights, clock=clock, cost_model=cm,
+            max_batch=max_batch, max_len=max_len, block_size=bs,
+            n_blocks=n_blocks, chunk_size=chunk) for _ in range(r)]
+        cl = ServingCluster(
+            replicas, policy=policy,
             shed_wait_s=float(params.get("shed_wait_s", 30.0)))
         return cl, clock
 
@@ -885,6 +889,9 @@ register(Experiment(
     grid={"shapes": ("1x1,2x1,1x2,2x2",)},
     quick_grid={"shapes": ("1x1,1x2",)},
     runner=run_sharded_decode_cell,
+    # a CPU-mesh correctness check: on an accelerator the child would
+    # report forced CPU devices under this name, so it refuses instead
+    backends=("cpu",),
     cost_per_cell_s=300.0,
     tags=("serve", "sharding", "costmodel"),
 ))

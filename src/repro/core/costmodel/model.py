@@ -27,7 +27,7 @@ from repro.core.costmodel.calibration import (Calibration, canon_dtype,
 from repro.core.costmodel.instruction import InstructionLayer, IssueCost
 from repro.core.costmodel.memory import MemoryLayer
 from repro.core.costmodel.mxu import MXULayer
-from repro.core.perfmodel.hardware import SPECS, TPU_V5E, HardwareSpec
+from repro.core.perfmodel.hardware import SPECS, HardwareSpec
 
 # calibration "hardware" strings -> HardwareSpec names
 _HW_ALIASES = {
@@ -80,7 +80,12 @@ def _resolve_hw(cal: Calibration,
     if hw is not None:
         return hw
     name = _HW_ALIASES.get(cal.hardware, cal.hardware)
-    return SPECS.get(name, TPU_V5E)
+    if name not in SPECS:
+        raise ValueError(
+            f"calibration {cal.name!r} names hardware {cal.hardware!r}, "
+            f"which has no HardwareSpec (known: {sorted(SPECS)}); pass "
+            "hw= explicitly")
+    return SPECS[name]
 
 
 class CostModel:

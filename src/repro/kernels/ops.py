@@ -26,6 +26,7 @@ from repro.kernels import (flash_attention as _fa, microbench_alu as _alu,
                            microbench_chase as _chase, mxu_probe as _mxu,
                            paged_attention as _pa, ssm_scan as _ssm,
                            wkv6 as _wkv)
+from repro.sharding import ctx
 
 # kernel name -> default launch config (the pre-autotuner hardcoded values)
 KERNEL_DEFAULTS = {name: dict(t.default_config)
@@ -81,9 +82,16 @@ def flash_attention(q, k, v, causal=True, window=None, softcap=None,
 
 @functools.partial(jax.jit, static_argnames=("scale", "window", "softcap",
                                              "interpret", "hbm",
-                                             "num_splits"))
+                                             "num_splits", "mesh"))
 def _pa_jit(q, k_pages, v_pages, block_tables, context_lens, scale, window,
-            softcap, interpret, hbm, num_splits):
+            softcap, interpret, hbm, num_splits, mesh):
+    if mesh is not None:
+        # a sharded replica's step: GSPMD cannot partition the kernel, so
+        # it runs per shard on the local heads and batch rows
+        return _pa.paged_attention_sharded(
+            q, k_pages, v_pages, block_tables, context_lens, mesh,
+            scale=scale, window=window, softcap=softcap,
+            num_splits=num_splits, hbm=hbm, interpret=interpret)
     fn = _pa.paged_attention_hbm if hbm else _pa.paged_attention
     return fn(q, k_pages, v_pages, block_tables, context_lens, scale=scale,
               window=window, softcap=softcap, num_splits=num_splits,
@@ -108,7 +116,10 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     the default on real TPUs, where staging a serving-sized pool into
     VMEM cannot fly.  Off-TPU the staged lowering stays the default
     (interpret-mode DMA is slower); pass ``hbm=True`` to exercise the
-    production path under interpret mode (what CPU CI does)."""
+    production path under interpret mode (what CPU CI does).
+
+    Inside ``sharding.ctx.use_kernel_mesh`` (a sharded replica's step)
+    the kernel runs per shard through ``paged_attention_sharded``."""
     interpret = _default_interpret() if interpret is None else interpret
     if hbm is None:
         hbm = jax.default_backend() == "tpu"
@@ -121,7 +132,8 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
                               explicit={"num_splits": num_splits})
     splits = max(min(int(c.get("num_splits", 1)), NB), 1)
     return _pa_jit(q, k_pages, v_pages, block_tables, context_lens, scale,
-                   window, softcap, interpret, bool(hbm), splits)
+                   window, softcap, interpret, bool(hbm), splits,
+                   ctx.kernel_mesh())
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))

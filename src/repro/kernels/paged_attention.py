@@ -8,13 +8,15 @@ the block size *is* the memory-access granularity, which is what the
 paper's hierarchy tables price.
 
 Grid is ``(batch, heads)`` — or ``(batch, heads, num_splits)`` in the
-split-KV "flash-decoding" form.  The GQA page panel for a query head
-resolves in the BlockSpec index_map (like ``flash_attention``), and the
-inner loop walks the sequence's valid pages with the online-softmax
-(m, l, acc) recurrence.  Page ids are data (loaded from the block-table
-ref), so the K/V loads use ``pl.ds`` dynamic slices; the loop trip count
-is the sequence's own ``ceil(ctx / block_size)``, so short contexts cost
-few iterations regardless of the table width.
+split-KV "flash-decoding" form.  A query head's KV head is
+``h // (H / KH)``, and the inner loop walks the sequence's valid pages
+with the online-softmax (m, l, acc) recurrence.  Block tables and
+context lengths are scalar-prefetched into SMEM
+(``PrefetchScalarGridSpec``), so page ids and the loop trip count — the
+sequence's own ``ceil(ctx / block_size)`` — are scalars: short contexts
+cost few iterations regardless of the table width.  q and the outputs
+carry a unit axis before ``D`` so every block's last two dimensions
+equal the array's, which the TPU's (8, 128) tiling rule accepts.
 
 Split-KV decoding (``num_splits > 1``): one ``(b, h)`` cell otherwise
 serializes the whole context on one core while the rest of the chip
@@ -42,9 +44,12 @@ Two lowerings share one wrapper signature:
   ``v_pages`` stay in ``ANY``/HBM memory space and each loop iteration
   async-copies only the table-selected page into a double-buffered VMEM
   scratch (page ``j+1``'s DMA is issued before page ``j`` is consumed),
-  so VMEM holds exactly two K pages + two V pages + the q/acc rows —
-  the pipelined working set the autotuner's ``space._pa_vmem`` prices,
-  independent of pool size.  The double-buffer pipeline is per-split:
+  so VMEM holds exactly two K pages + two V pages + the q/acc rows,
+  independent of pool size.  A copy carries all ``KH`` heads of its
+  page: the head axis is tiled, and the DMA cannot cut one head out of
+  it, so each ``(b, h)`` cell reads ``KH`` times the bytes it attends
+  (grouping the query heads of one KV head into one cell removes
+  that).  The double-buffer pipeline is per-split:
   each split's slice walks its own consecutive ``j`` range, so the
   two-slot parity scheme works unchanged and VMEM still holds exactly
   two K + two V pages per grid cell regardless of ``num_splits``.
@@ -69,29 +74,33 @@ def _attend_page(q, k, v, raw, j, ctx, carry, *, window, softcap,
                  block_size):
     """One online-softmax step over page ``j`` — shared by all four
     kernel bodies so the split and unsplit lowerings compute the same
-    math on the same page in the same order."""
+    math on the same page in the same order.  Every value is 2-D
+    (``m``/``l`` are ``[1, 1]``, ``acc`` is ``[1, D]``): the TPU's
+    vector layouts tile the last two dimensions."""
     m, l, acc = carry
-    s = q @ k.T                                           # [1, bs]
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)  # [1, bs]
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
-    k_pos = j * block_size + jax.lax.iota(jnp.int32, block_size)
+    k_pos = j * block_size + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block_size), 1)
     # in-ctx positions whose table entry is -1 (unbacked page) must
     # mask, not attend the clipped page 0 — matches the ref oracle
     mask = (k_pos < ctx) & (raw >= 0)                     # causal by layout
     if window is not None:
         mask &= (ctx - 1 - k_pos) < window
-    s = jnp.where(mask[None, :], s, NEG_INF)
-    m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    s = jnp.where(mask, s, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     alpha = jnp.exp(m - m_new)
-    l_new = l * alpha + jnp.sum(p, axis=-1)
-    acc_new = acc * alpha[:, None] + p @ v
+    l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_new = acc * alpha + jnp.dot(p, v, preferred_element_type=jnp.float32)
     return m_new, l_new, acc_new
 
 
 def _carry_init(D):
-    return (jnp.full((1,), NEG_INF, jnp.float32),
-            jnp.zeros((1,), jnp.float32),
+    return (jnp.full((1, 1), NEG_INF, jnp.float32),
+            jnp.zeros((1, 1), jnp.float32),
             jnp.zeros((1, D), jnp.float32))
 
 
@@ -118,163 +127,57 @@ def _merge_partials(m, l, acc, out_dtype):
     return (out / jnp.maximum(l_star, 1e-30)[..., None]).astype(out_dtype)
 
 
-def _pa_kernel(q_ref, bt_ref, ctx_ref, k_ref, v_ref, o_ref, *, scale,
-               window, softcap, block_size, n_pages):
-    q = q_ref[0].astype(jnp.float32) * scale              # [1, D]
-    D = q.shape[-1]
-    ctx = ctx_ref[0, 0]
-    n_valid = pl.cdiv(ctx, block_size)                    # traced trip count
+def _row(bt_ref, ctx_ref, n_blocks):
+    """This grid cell's sequence: its context length and a reader of its
+    block-table entries (both scalar-prefetched into SMEM)."""
+    b = pl.program_id(0)
+    return ctx_ref[b], lambda j: bt_ref[b * n_blocks + j]
+
+
+def _head_rows(page, kh):
+    """KV head ``kh``'s ``[bs, D]`` rows of one ``[bs, KH, D]`` page,
+    kept with a one-hot sum: the head axis is the tiled second-minor
+    axis, which neither a dynamic index nor a one-head DMA slice may cut."""
+    page = page.astype(jnp.float32)
+    sel = jax.lax.broadcasted_iota(jnp.int32, page.shape, 1) == kh
+    return jnp.sum(jnp.where(sel, page, 0.0), axis=1)
+
+
+def _pa_staged_loop(q_ref, table, ctx, k_ref, v_ref, *, scale, window,
+                    softcap, block_size, n_pages, kh, lo, hi):
+    """The recurrence over pages ``[lo, hi)`` of the VMEM-staged pool."""
+    q = q_ref[...].astype(jnp.float32) * scale            # [1, D]
 
     def body(j, carry):
-        raw = bt_ref[0, j]
+        raw = table(j)
         pid = jnp.clip(raw, 0, n_pages - 1)
-        k = k_ref[pl.ds(pid, 1)][0, :, 0].astype(jnp.float32)  # [bs, D]
-        v = v_ref[pl.ds(pid, 1)][0, :, 0].astype(jnp.float32)
-        return _attend_page(q, k, v, raw, j, ctx, carry, window=window,
-                            softcap=softcap, block_size=block_size)
+        return _attend_page(q, _head_rows(k_ref[pid], kh),
+                            _head_rows(v_ref[pid], kh), raw, j, ctx,
+                            carry, window=window, softcap=softcap,
+                            block_size=block_size)
 
-    _, l, acc = jax.lax.fori_loop(0, n_valid, body, _carry_init(D))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-
-
-def _pa_split_kernel(q_ref, bt_ref, ctx_ref, k_ref, v_ref, m_ref, l_ref,
-                     acc_ref, *, scale, window, softcap, block_size,
-                     n_pages, num_splits):
-    """First flash-decoding pass, staged-pool form: the ``(b, h, s)``
-    cell runs the recurrence over its slice of valid pages and writes
-    the partial (m, l, acc) row instead of a normalized output."""
-    q = q_ref[0].astype(jnp.float32) * scale              # [1, D]
-    D = q.shape[-1]
-    ctx = ctx_ref[0, 0]
-    lo, hi = _split_bounds(ctx, block_size, num_splits)
-
-    def body(j, carry):
-        raw = bt_ref[0, j]
-        pid = jnp.clip(raw, 0, n_pages - 1)
-        k = k_ref[pl.ds(pid, 1)][0, :, 0].astype(jnp.float32)  # [bs, D]
-        v = v_ref[pl.ds(pid, 1)][0, :, 0].astype(jnp.float32)
-        return _attend_page(q, k, v, raw, j, ctx, carry, window=window,
-                            softcap=softcap, block_size=block_size)
-
-    m, l, acc = jax.lax.fori_loop(lo, hi, body, _carry_init(D))
-    m_ref[0, 0] = m
-    l_ref[0, 0] = l
-    acc_ref[0, 0] = acc
+    return jax.lax.fori_loop(lo, hi, body, _carry_init(q.shape[-1]))
 
 
-def _pa_specs(B, H, D, NB, P, bs, group, *, hbm, num_splits):
-    """in/out BlockSpecs + out_shape for either grid form.  The split
-    form's outputs are the f32 partial rows; the merge runs in plain
-    jnp outside the kernel (tiny: [B,H,S] rows)."""
-    if num_splits == 1:
-        q_map = lambda b, h: (b, h, 0)                     # noqa: E731
-        bt_map = lambda b, h: (b, 0)                       # noqa: E731
-        pool_map = lambda b, h, g=group: (0, 0, h // g, 0)  # noqa: E731
-        out_specs = pl.BlockSpec((1, 1, D), q_map)
-        out_shape = None                                   # caller fills
-    else:
-        q_map = lambda b, h, s: (b, h, 0)                  # noqa: E731
-        bt_map = lambda b, h, s: (b, 0)                    # noqa: E731
-        pool_map = lambda b, h, s, g=group: (0, 0, h // g, 0)  # noqa: E731
-        part_map = lambda b, h, s: (b, h, s)               # noqa: E731
-        acc_map = lambda b, h, s: (b, h, s, 0)             # noqa: E731
-        out_specs = [
-            pl.BlockSpec((1, 1, 1), part_map),
-            pl.BlockSpec((1, 1, 1), part_map),
-            pl.BlockSpec((1, 1, 1, D), acc_map),
-        ]
-        out_shape = [
-            jax.ShapeDtypeStruct((B, H, num_splits), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, num_splits), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, num_splits, D), jnp.float32),
-        ]
-    pool_spec = (pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
-                 if hbm else pl.BlockSpec((P, bs, 1, D), pool_map))
-    in_specs = [
-        pl.BlockSpec((1, 1, D), q_map),
-        pl.BlockSpec((1, NB), bt_map),
-        pl.BlockSpec((1, 1), bt_map),
-        pool_spec,
-        pool_spec,
-    ]
-    return in_specs, out_specs, out_shape
-
-
-def paged_attention(q, k_pages, v_pages, block_tables, context_lens, *,
-                    scale=None, window=None, softcap=None, num_splits=1,
-                    interpret=False):
-    """q [B,H,D]; k/v_pages [P,bs,KH,D]; block_tables [B,NB] int32 (-1 =
-    unbacked); context_lens [B] int32 -> [B,H,D].
-
-    Attention of one new token per sequence over its paged context: the
-    query position is ``context_lens - 1`` (causality holds by
-    construction — only written positions are < ctx), with optional
-    sliding ``window`` and logit ``softcap`` matching the flash kernel.
-    Rows with ``context_lens == 0`` produce zeros (masked everywhere).
-
-    ``num_splits > 1`` selects the split-KV flash-decoding form: grid
-    ``(B, H, num_splits)``, per-split partial (m, l, acc) rows, and a
-    log-sum-exp merge pass — same outputs up to summation order.
-    """
-    B, H, D = q.shape
-    P, bs, KH, _ = k_pages.shape
-    NB = block_tables.shape[1]
-    scale = scale if scale is not None else D ** -0.5
-    group = H // KH
-    num_splits = max(int(num_splits), 1)
-
-    in_specs, out_specs, out_shape = _pa_specs(
-        B, H, D, NB, P, bs, group, hbm=False, num_splits=num_splits)
-    operands = (q,
-                jnp.asarray(block_tables, jnp.int32),
-                jnp.asarray(context_lens, jnp.int32).reshape(B, 1),
-                k_pages, v_pages)
-
-    if num_splits == 1:
-        return pl.pallas_call(
-            functools.partial(_pa_kernel, scale=scale, window=window,
-                              softcap=softcap, block_size=bs, n_pages=P),
-            grid=(B, H),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-            interpret=interpret,
-        )(*operands)
-
-    m, l, acc = pl.pallas_call(
-        functools.partial(_pa_split_kernel, scale=scale, window=window,
-                          softcap=softcap, block_size=bs, n_pages=P,
-                          num_splits=num_splits),
-        grid=(B, H, num_splits),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*operands)
-    return _merge_partials(m, l, acc, q.dtype)
-
-
-# ---------------------------------------------------------------------------
-# the HBM-resident lowering
-# ---------------------------------------------------------------------------
-
-
-def _pa_hbm_loop(q_ref, bt_ref, ctx, k_hbm, v_hbm, *, scale, window,
+def _pa_hbm_loop(q_ref, table, ctx, k_hbm, v_hbm, *, scale, window,
                  softcap, block_size, n_pages, kh, lo, hi):
     """The double-buffered DMA pipeline over pages ``[lo, hi)``: issue
     page ``j+1``'s copies before waiting on page ``j`` so the gather
-    overlaps the compute.  ``j`` runs consecutively within the range,
-    so the two-slot parity scheme (``slot = j % 2``) holds for any
-    split's ``lo`` — VMEM cost is two K + two V pages regardless of
-    how many splits share the sequence.  Returns the final carry."""
-    q = q_ref[0].astype(jnp.float32) * scale              # [1, D]
+    overlaps the compute.  A copy moves the whole ``[bs, KH, D]`` page
+    (the DMA cannot cut one head out of the tiled head axis) and
+    ``_head_rows`` keeps this cell's head.  ``j`` runs consecutively
+    within the range, so the two-slot parity scheme (``slot = j % 2``)
+    holds for any split's ``lo`` — VMEM cost is two K + two V pages
+    regardless of how many splits share the sequence.  Returns the
+    final carry."""
+    q = q_ref[...].astype(jnp.float32) * scale            # [1, D]
     D = q.shape[-1]
 
     def body(k_buf, v_buf, k_sem, v_sem):
         def dma(buf, hbm, sem, slot, j):
-            pid = jnp.clip(bt_ref[0, j], 0, n_pages - 1)
-            return pltpu.make_async_copy(hbm.at[pid, :, kh, :],
-                                         buf.at[slot], sem.at[slot])
+            pid = jnp.clip(table(j), 0, n_pages - 1)
+            return pltpu.make_async_copy(hbm.at[pid], buf.at[slot],
+                                         sem.at[slot])
 
         @pl.when(hi > lo)
         def _():
@@ -293,9 +196,9 @@ def _pa_hbm_loop(q_ref, bt_ref, ctx, k_hbm, v_hbm, *, scale, window,
 
             dma(k_buf, k_hbm, k_sem, slot, j).wait()
             dma(v_buf, v_hbm, v_sem, slot, j).wait()
-            k = k_buf[slot].astype(jnp.float32)           # [bs, D]
-            v = v_buf[slot].astype(jnp.float32)
-            return _attend_page(q, k, v, bt_ref[0, j], j, ctx, carry,
+            k = _head_rows(k_buf[slot], kh)               # [bs, D]
+            v = _head_rows(v_buf[slot], kh)
+            return _attend_page(q, k, v, table(j), j, ctx, carry,
                                 window=window, softcap=softcap,
                                 block_size=block_size)
 
@@ -303,43 +206,112 @@ def _pa_hbm_loop(q_ref, bt_ref, ctx, k_hbm, v_hbm, *, scale, window,
 
     return pl.run_scoped(
         body,
-        k_buf=pltpu.VMEM((2, block_size, q_ref.shape[-1]), k_hbm.dtype),
-        v_buf=pltpu.VMEM((2, block_size, q_ref.shape[-1]), v_hbm.dtype),
+        k_buf=pltpu.VMEM((2,) + k_hbm.shape[1:], k_hbm.dtype),
+        v_buf=pltpu.VMEM((2,) + v_hbm.shape[1:], v_hbm.dtype),
         k_sem=pltpu.SemaphoreType.DMA((2,)),
         v_sem=pltpu.SemaphoreType.DMA((2,)))
 
 
-def _pa_hbm_kernel(q_ref, bt_ref, ctx_ref, k_hbm, v_hbm, o_ref, *, scale,
-                   window, softcap, block_size, n_pages, group):
-    """Same online-softmax recurrence as ``_pa_kernel``, but ``k_hbm`` /
-    ``v_hbm`` are unblocked ``ANY``-space refs of the WHOLE pool, walked
-    through the double-buffered DMA pipeline (``_pa_hbm_loop``)."""
-    ctx = ctx_ref[0, 0]
-    n_valid = pl.cdiv(ctx, block_size)                    # traced trip count
+def _pa_kernel(bt_ref, ctx_ref, q_ref, k_ref, v_ref, *out_refs, loop,
+               n_blocks, group, num_splits, **kw):
+    """One ``(b, h)`` — or ``(b, h, s)`` split — grid cell of either
+    lowering.  ``loop`` walks the cell's page range (staged pool or HBM
+    DMA pipeline); the unsplit form writes the normalized output row,
+    the split form its partial ``(m, l, acc)`` row."""
+    ctx, table = _row(bt_ref, ctx_ref, n_blocks)
     kh = pl.program_id(1) // group                        # GQA panel
-    _, l, acc = _pa_hbm_loop(q_ref, bt_ref, ctx, k_hbm, v_hbm, scale=scale,
-                             window=window, softcap=softcap,
-                             block_size=block_size, n_pages=n_pages, kh=kh,
-                             lo=jnp.int32(0), hi=n_valid)
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+    if num_splits == 1:
+        lo, hi = jnp.int32(0), pl.cdiv(ctx, kw["block_size"])
+    else:
+        lo, hi = _split_bounds(ctx, kw["block_size"], num_splits)
+    m, l, acc = loop(q_ref, table, ctx, k_ref, v_ref, kh=kh, lo=lo, hi=hi,
+                     **kw)
+    if num_splits == 1:
+        (o_ref,) = out_refs
+        o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    else:
+        m_ref, l_ref, acc_ref = out_refs
+        m_ref[...] = m
+        l_ref[...] = l
+        acc_ref[...] = acc
 
 
-def _pa_split_hbm_kernel(q_ref, bt_ref, ctx_ref, k_hbm, v_hbm, m_ref,
-                         l_ref, acc_ref, *, scale, window, softcap,
-                         block_size, n_pages, group, num_splits):
-    """First flash-decoding pass, HBM-resident form: the ``(b, h, s)``
-    cell pipelines only its own page slice through the two-slot VMEM
-    scratch and writes the partial (m, l, acc) row."""
-    ctx = ctx_ref[0, 0]
-    kh = pl.program_id(1) // group                        # GQA panel
-    lo, hi = _split_bounds(ctx, block_size, num_splits)
-    m, l, acc = _pa_hbm_loop(q_ref, bt_ref, ctx, k_hbm, v_hbm, scale=scale,
-                             window=window, softcap=softcap,
-                             block_size=block_size, n_pages=n_pages, kh=kh,
-                             lo=lo, hi=hi)
-    m_ref[0, 0] = m
-    l_ref[0, 0] = l
-    acc_ref[0, 0] = acc
+def _paged_call(q, k_pages, v_pages, block_tables, context_lens, *, hbm,
+                scale, window, softcap, num_splits, interpret):
+    """Build and run the ``pallas_call`` of either lowering.
+
+    Block tables (flattened) and context lengths are scalar-prefetched
+    into SMEM, so page ids and trip counts are read as scalars.  q and
+    the outputs carry a unit axis before ``D`` (``[B, H, 1, D]``) so the
+    last two block dimensions equal the array's, as the TPU's tiling
+    requires; the head and split axes are squeezed grid axes."""
+    B, H, D = q.shape
+    P, bs, KH, _ = k_pages.shape
+    NB = block_tables.shape[1]
+    scale = scale if scale is not None else D ** -0.5
+    num_splits = max(int(num_splits), 1)
+    grid = (B, H) if num_splits == 1 else (B, H, num_splits)
+    row_map = lambda b, h, *_: (b, h, 0, 0)               # noqa: E731
+    if hbm:
+        pool_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
+    else:
+        pool_spec = pl.BlockSpec((P, bs, KH, D), lambda *_: (0, 0, 0, 0))
+    in_specs = [pl.BlockSpec((None, None, 1, D), row_map), pool_spec,
+                pool_spec]
+    if num_splits == 1:
+        out_specs = pl.BlockSpec((None, None, 1, D), row_map)
+        out_shape = jax.ShapeDtypeStruct((B, H, 1, D), q.dtype)
+    else:
+        part_map = lambda b, h, s, *_: (b, h, s, 0, 0)    # noqa: E731
+        out_specs = [pl.BlockSpec((None, None, None, 1, 1), part_map),
+                     pl.BlockSpec((None, None, None, 1, 1), part_map),
+                     pl.BlockSpec((None, None, None, 1, D), part_map)]
+        out_shape = [
+            jax.ShapeDtypeStruct((B, H, num_splits, 1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, num_splits, 1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, num_splits, 1, D), jnp.float32)]
+    kernel = functools.partial(
+        _pa_kernel, loop=_pa_hbm_loop if hbm else _pa_staged_loop,
+        n_blocks=NB, group=H // KH, num_splits=num_splits, scale=scale,
+        window=window, softcap=softcap, block_size=bs, n_pages=P)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+            out_specs=out_specs),
+        out_shape=out_shape,
+        interpret=interpret,
+    )(jnp.asarray(block_tables, jnp.int32).reshape(B * NB),
+      jnp.asarray(context_lens, jnp.int32).reshape(B),
+      q.reshape(B, H, 1, D), k_pages, v_pages)
+    if num_splits == 1:
+        return out.reshape(B, H, D)
+    m, l, acc = out
+    return _merge_partials(m.reshape(B, H, num_splits),
+                           l.reshape(B, H, num_splits),
+                           acc.reshape(B, H, num_splits, D), q.dtype)
+
+
+def paged_attention(q, k_pages, v_pages, block_tables, context_lens, *,
+                    scale=None, window=None, softcap=None, num_splits=1,
+                    interpret=False):
+    """q [B,H,D]; k/v_pages [P,bs,KH,D]; block_tables [B,NB] int32 (-1 =
+    unbacked); context_lens [B] int32 -> [B,H,D].
+
+    Attention of one new token per sequence over its paged context: the
+    query position is ``context_lens - 1`` (causality holds by
+    construction — only written positions are < ctx), with optional
+    sliding ``window`` and logit ``softcap`` matching the flash kernel.
+    Rows with ``context_lens == 0`` produce zeros (masked everywhere).
+
+    ``num_splits > 1`` selects the split-KV flash-decoding form: grid
+    ``(B, H, num_splits)``, per-split partial (m, l, acc) rows, and a
+    log-sum-exp merge pass — same outputs up to summation order.
+    """
+    return _paged_call(q, k_pages, v_pages, block_tables, context_lens,
+                       hbm=False, scale=scale, window=window,
+                       softcap=softcap, num_splits=num_splits,
+                       interpret=interpret)
 
 
 def paged_attention_hbm(q, k_pages, v_pages, block_tables, context_lens, *,
@@ -350,43 +322,10 @@ def paged_attention_hbm(q, k_pages, v_pages, block_tables, context_lens, *,
     lowering for pools far larger than VMEM.  Same contract and oracle
     (``ref.paged_attention_ref``) as the staged lowering, including the
     ``num_splits`` flash-decoding form."""
-    B, H, D = q.shape
-    P, bs, KH, _ = k_pages.shape
-    NB = block_tables.shape[1]
-    scale = scale if scale is not None else D ** -0.5
-    group = H // KH
-    num_splits = max(int(num_splits), 1)
-
-    in_specs, out_specs, out_shape = _pa_specs(
-        B, H, D, NB, P, bs, group, hbm=True, num_splits=num_splits)
-    operands = (q,
-                jnp.asarray(block_tables, jnp.int32),
-                jnp.asarray(context_lens, jnp.int32).reshape(B, 1),
-                k_pages, v_pages)
-
-    if num_splits == 1:
-        return pl.pallas_call(
-            functools.partial(_pa_hbm_kernel, scale=scale, window=window,
-                              softcap=softcap, block_size=bs, n_pages=P,
-                              group=group),
-            grid=(B, H),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-            interpret=interpret,
-        )(*operands)
-
-    m, l, acc = pl.pallas_call(
-        functools.partial(_pa_split_hbm_kernel, scale=scale, window=window,
-                          softcap=softcap, block_size=bs, n_pages=P,
-                          group=group, num_splits=num_splits),
-        grid=(B, H, num_splits),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*operands)
-    return _merge_partials(m, l, acc, q.dtype)
+    return _paged_call(q, k_pages, v_pages, block_tables, context_lens,
+                       hbm=True, scale=scale, window=window,
+                       softcap=softcap, num_splits=num_splits,
+                       interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -411,7 +350,6 @@ def paged_attention_sharded(q, k_pages, v_pages, block_tables, context_lens,
     merge stays shard-local).  Falls back to the unsharded call when the
     mesh cannot divide heads/batch evenly (the ``sanitize_specs``
     replication rule) or has no parallelism at all."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     B, H, D = q.shape
@@ -428,13 +366,13 @@ def paged_attention_sharded(q, k_pages, v_pages, block_tables, context_lens,
         return call(q, k_pages, v_pages, block_tables, context_lens)
     bax = "data" if (d_sz > 1 and batch_ok) else None
     hax = "model" if m_sz > 1 else None
-    return shard_map(
-        call, mesh,
+    return jax.shard_map(
+        call, mesh=mesh,
         in_specs=(P(bax, hax, None),          # q: rows x head slice
                   P(None, None, hax, None),   # pools: KV-head slice
                   P(None, None, hax, None),
                   P(bax, None),               # tables: replicated per shard
                   P(bax,)),                   # context lengths
         out_specs=P(bax, hax, None),
-        check_rep=False,
+        check_vma=False,
     )(q, k_pages, v_pages, block_tables, context_lens)

@@ -30,6 +30,8 @@ def main():
         run_cell(args.arch, args.cell, args.multi_pod)
         return
 
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     import jax
     import numpy as np
     from repro.configs import get_config, reduced

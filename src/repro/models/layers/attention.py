@@ -312,10 +312,9 @@ def attention(p, x, *, cfg, positions, is_global, theta=None,
     # is_global is a python bool, or the config has no window at all.
     # Under a ('data','model') serving mesh the jnp paged path partitions
     # through GSPMD (pool KV heads over 'model' — see ``paged_pool_spec``);
-    # the explicit per-shard kernel route for TPU meshes is
-    # ``kernels.paged_attention.paged_attention_sharded`` (head cells of
-    # the (B,H,num_splits) grid are independent, so the shard_map split
-    # runs the same kernel on local head slices).
+    # the kernel runs per shard on local head slices instead
+    # (``kops.paged_attention`` routes through ``paged_attention_sharded``
+    # under the engine's ``sharding.ctx.use_kernel_mesh``).
     static_global = isinstance(is_global, bool)
     use_paged_kernel = (
         block_tables is not None and cfg.use_pallas and Sq == 1

@@ -6,7 +6,8 @@ directly, or handed a device budget + serving shape and let
 ``sharding.rank_cluster_topologies`` choose — the same calibrated
 pricing that ranks per-replica meshes decides how many replicas the
 budget buys (the chosen :class:`~repro.sharding.plans.ClusterTopology`
-is kept on ``cluster.topology`` for reporting).  Every replica is a
+is kept on ``cluster.topology`` for reporting).  It places every
+replica on its own devices and refuses a budget the host cannot hold.  Every replica is a
 full engine with its own KV pool, scheduler, and (optionally) its own
 bound TelemetryController from a :class:`ClusterTelemetry`; they share
 one clock so cross-replica latency accounting is comparable.
@@ -61,32 +62,33 @@ class ServingCluster:
     # -- construction ---------------------------------------------------------
     @classmethod
     def build(cls, model, params, n_replicas: Optional[int] = None, *,
-              engine: str = "paged", policy="cost_aware",
-              clock=None, cost_model=None, telemetry=None,
-              shed_wait_s: Optional[float] = None, max_reroutes: int = 3,
-              n_devices: Optional[int] = None, cell=None,
-              **engine_kwargs) -> "ServingCluster":
-        """Stand up a cluster of identical replicas.
+              policy="cost_aware", clock=None, cost_model=None,
+              telemetry=None, shed_wait_s: Optional[float] = None,
+              max_reroutes: int = 3, n_devices: Optional[int] = None,
+              cell=None, **engine_kwargs) -> "ServingCluster":
+        """Stand up a cluster of identical paged replicas, each on its own
+        devices.
 
-        Either pass ``n_replicas`` directly, or pass a device budget
-        (``n_devices``) plus the serving shape (``cell``) and the
-        replica count is read off ``rank_cluster_topologies(...)[0]`` —
-        the cost-model-chosen topology.  ``engine_kwargs`` (max_batch,
+        Either pass ``n_replicas`` directly (one chip each), or pass a
+        device budget (``n_devices``) plus the serving shape (``cell``)
+        and the replica count and per-replica mesh are read off
+        ``rank_cluster_topologies(...)[0]`` — the cost-model-chosen
+        topology.  Replica ``i`` gets the ``i``-th disjoint slice of
+        ``jax.devices()`` (``launch.mesh.slice_devices``) as a
+        ``('data', 'model')`` mesh, a one-chip replica a ``(1, 1)`` mesh,
+        so its params and KV pool live on its own chips.  A budget larger
+        than the devices present raises.  ``engine_kwargs`` (max_batch,
         n_blocks, chunk_size, fused, ...) go to every replica verbatim.
         ``telemetry`` may be a :class:`ClusterTelemetry` (one controller
         per replica) — a single TelemetryController cannot be shared,
         its ``bind`` refuses a second engine.
 
-        When the budget came with a topology whose replicas span more
-        than one chip (``plan.data x plan.model > 1``) and the process
-        actually HAS that many devices, each paged replica is
-        instantiated on its own device sub-slice
-        (``launch.mesh.slice_devices``) with the per-replica mesh built
-        from the ranked plan — the priced factorization becomes the
-        physical layout.  With fewer physical devices than the budget
-        (the analytic/simulation case: pricing an 8-chip cluster from a
-        1-chip host) replicas stay unsharded, exactly as before.
+        A simulated cluster (replicas sharing one host device on a
+        ``SimClock``) is built from its engines directly:
+        ``ServingCluster(replicas, ...)``.
         """
+        from repro.launch.mesh import make_host_mesh, slice_devices
+        from repro.serve.engine import PagedServingEngine
         topology = None
         if n_replicas is None:
             if n_devices is None or cell is None:
@@ -98,35 +100,15 @@ class ServingCluster:
             n_replicas = topology.n_replicas
         if n_replicas < 1:
             raise ValueError(f"n_replicas must be >= 1, got {n_replicas}")
-
-        if engine == "paged":
-            from repro.serve.engine import PagedServingEngine as Engine
-        elif engine == "slot":
-            from repro.serve.engine import ServingEngine as Engine
-        else:
-            raise ValueError(f"unknown engine kind {engine!r} "
-                             f"(want 'paged' or 'slot')")
-        meshes: List = [None] * n_replicas
-        if (engine == "paged" and topology is not None
-                and topology.devices_per_replica > 1
-                and "mesh" not in engine_kwargs):
-            import jax
-            from repro.launch.mesh import make_host_mesh, slice_devices
-            per = topology.devices_per_replica
-            if n_replicas * per <= len(jax.devices()):
-                meshes = [
-                    make_host_mesh(model_axis=topology.plan.model,
-                                   devices=devs)
-                    for devs in slice_devices(n_replicas, per)]
+        per = topology.devices_per_replica if topology is not None else 1
+        model_axis = topology.plan.model if topology is not None else 1
         replicas = []
-        for i in range(n_replicas):
+        for i, devs in enumerate(slice_devices(n_replicas, per)):
             controller = telemetry.controller(i) if telemetry else None
-            kw = dict(engine_kwargs)
-            if meshes[i] is not None:
-                kw["mesh"] = meshes[i]
-            replicas.append(Engine(model, params, clock=clock,
-                                   cost_model=cost_model,
-                                   telemetry=controller, **kw))
+            mesh = make_host_mesh(model_axis=model_axis, devices=devs)
+            replicas.append(PagedServingEngine(
+                model, params, clock=clock, cost_model=cost_model,
+                telemetry=controller, mesh=mesh, **engine_kwargs))
         return cls(replicas, policy=policy, shed_wait_s=shed_wait_s,
                    max_reroutes=max_reroutes, telemetry=telemetry,
                    topology=topology)

@@ -87,6 +87,7 @@ from repro.models.zoo import Model, fused_decode_step
 from repro.serve.paging import (BlockAllocator, blocks_for_tokens,
                                 remap_table)
 from repro.serve.scheduler import ChunkedPrefillScheduler
+from repro.sharding import ctx
 
 
 @dataclasses.dataclass
@@ -579,6 +580,32 @@ class _Row:
     dispatched: int = 0             # fused: decode dispatches incl. in-flight
 
 
+def paged_decode_fn(step_fn, mesh=None):
+    """The paged engine's fused decode step before jit: one greedy token
+    per row, the ``[2, B]`` echo of inputs and outputs, and masked rows
+    (``pos < 0``) keeping their resident token.  With a replica ``mesh``
+    the paged-attention kernel, which GSPMD cannot partition, runs per
+    shard (``sharding.ctx.use_kernel_mesh``)."""
+    def fused_decode(params, cache, toks, pos, bt):
+        with ctx.use_kernel_mesh(mesh):
+            nxt, cache = step_fn(params, cache, toks[:, None], pos, bt)
+        io = jnp.stack([toks, nxt])
+        return io, jnp.where(pos >= 0, nxt, toks), cache
+    return fused_decode
+
+
+def paged_chunk_fn(step_fn, mesh=None):
+    """The paged engine's fused prefill-chunk step before jit: only a
+    prompt's FINAL chunk writes its first token into the device token
+    array; intermediate chunks leave the row's slot untouched."""
+    def fused_chunk(params, cache, toks, start, bt, toks_dev, idx, final):
+        with ctx.use_kernel_mesh(mesh):
+            nxt, cache = step_fn(params, cache, toks, start, bt)
+        tok0 = jnp.where(final, nxt[0], toks_dev[idx])
+        return cache, toks_dev.at[idx].set(tok0)
+    return fused_chunk
+
+
 class PagedServingEngine(_TunedDispatch):
     """Continuous batching over a paged KV cache with chunked prefill.
 
@@ -699,20 +726,8 @@ class PagedServingEngine(_TunedDispatch):
         step_fn = _decode_step_fn(model)
         if fused:
             self._toks = self._dev(np.zeros(max_batch, np.int32), "batch")
-
-            def fused_decode(params, cache, toks, pos, bt):
-                nxt, cache = step_fn(params, cache, toks[:, None], pos, bt)
-                io = jnp.stack([toks, nxt])
-                # masked rows (pos < 0) keep their resident token
-                return io, jnp.where(pos >= 0, nxt, toks), cache
-
-            def fused_chunk(params, cache, toks, start, bt, toks_dev, idx,
-                            final):
-                nxt, cache = step_fn(params, cache, toks, start, bt)
-                # only a prompt's FINAL chunk yields its first token;
-                # intermediate chunks leave the row's slot untouched
-                tok0 = jnp.where(final, nxt[0], toks_dev[idx])
-                return cache, toks_dev.at[idx].set(tok0)
+            fused_decode = paged_decode_fn(step_fn, mesh)
+            fused_chunk = paged_chunk_fn(step_fn, mesh)
 
             if mesh is None:
                 self._decode = jax.jit(fused_decode, donate_argnums=(1,))

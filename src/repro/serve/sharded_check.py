@@ -102,6 +102,12 @@ def run_check(shapes: Sequence[Tuple[int, int]], *, n_req: int = 32,
     from repro.models.zoo import build_model
     from repro.serve.engine import PagedServingEngine
 
+    if jax.default_backend() != "cpu":
+        # the meshes are forced host devices; on an accelerator this
+        # would report a different program under the same name
+        raise RuntimeError("sharded_check runs on a forced multi-device "
+                           f"CPU host, not on {jax.default_backend()!r}; "
+                           "set JAX_PLATFORMS=cpu")
     cfg = reduced(ARCHS["gemma2-2b"], n_layers=2, vocab_size=128)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -179,7 +185,9 @@ def run_subprocess(shapes: Sequence[Tuple[int, int]], *, devices: int = 8,
                    timeout_s: float = 1200.0) -> dict:
     """Re-enter this module in a child process with
     ``--xla_force_host_platform_device_count=<devices>`` set before jax
-    initializes there, and return the parsed JSON doc."""
+    initializes there, and return the parsed JSON doc.  The child runs
+    on the CPU and refuses any other backend (``run_check``), so the
+    caller runs with ``JAX_PLATFORMS=cpu``."""
     env = dict(os.environ)
     flags = env.get("XLA_FLAGS", "")
     flags = " ".join(f for f in flags.split()
@@ -187,7 +195,6 @@ def run_subprocess(shapes: Sequence[Tuple[int, int]], *, devices: int = 8,
     env["XLA_FLAGS"] = (flags + " "
                        f"--xla_force_host_platform_device_count={devices}"
                        ).strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
     shape_arg = ",".join(f"{d}x{m}" for d, m in shapes)
     proc = subprocess.run(
         [sys.executable, "-m", "repro.serve.sharded_check",

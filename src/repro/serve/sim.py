@@ -119,11 +119,19 @@ class FakeModel:
         nxt = (tokens[:, -1] + 1) % self.vocab
         return jax.nn.one_hot(nxt, self.vocab), cache
 
-    def init_paged_cache(self, n_blocks, block_size):
+    def param_specs(self):
+        return None                 # no parameters to lay out
+
+    def init_paged_cache(self, n_blocks, block_size, mesh=None):
+        import jax
         import jax.numpy as jnp
         shape = (1, n_blocks, block_size, 1, 1)
-        return {"k": jnp.zeros(shape, jnp.bfloat16),
+        pool = {"k": jnp.zeros(shape, jnp.bfloat16),
                 "v": jnp.zeros(shape, jnp.bfloat16)}
+        if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+            pool = jax.device_put(pool, NamedSharding(mesh, PartitionSpec()))
+        return pool
 
 
 def expected_tokens(prompt, n, vocab, eos_id=None):

@@ -64,11 +64,15 @@ def train(model: Model, mesh, *, num_steps: int = 50,
     prev_tuner = autotune_mod.install(autotuner) \
         if autotuner is not None else None
     try:
-        return _train(model, mesh, num_steps=num_steps,
-                      global_batch=global_batch, seq_len=seq_len,
-                      ckpt_dir=ckpt_dir, ckpt_every=ckpt_every, lr=lr,
-                      seed=seed, hooks=hooks, cost_model=cost_model,
-                      log_prediction=log_prediction, autotuner=autotuner)
+        # the context mesh of this run only: activation constraints
+        # resolve against it, and it is gone when training returns
+        with jax.set_mesh(mesh):
+            return _train(model, mesh, num_steps=num_steps,
+                          global_batch=global_batch, seq_len=seq_len,
+                          ckpt_dir=ckpt_dir, ckpt_every=ckpt_every, lr=lr,
+                          seed=seed, hooks=hooks, cost_model=cost_model,
+                          log_prediction=log_prediction,
+                          autotuner=autotuner)
     finally:
         if autotuner is not None:
             autotune_mod.install(prev_tuner)
@@ -103,8 +107,6 @@ def _train(model: Model, mesh, *, num_steps, global_batch, seq_len,
     # ----- shardings / step ---------------------------------------------------
     from repro.configs.base import ShapeCell
     cell = ShapeCell("loop", "train", seq_len, global_batch)
-    if hasattr(jax, "set_mesh"):       # jax>=0.6; shardings below are explicit
-        jax.set_mesh(mesh)
     psh, osh, bsh, shapes, _ = train_shardings(model, optimizer, mesh, cell)
     accum = accum_steps_for(cfg, global_batch, n_batch_shards(mesh))
 

@@ -33,7 +33,10 @@ STEP = unit_latency(decode_s=0.5, chunk_s=0.25, overhead_s=0.01)
 
 
 def build_cluster(n, policy="cost_aware", clock=None, shed_wait_s=None,
-                  **kw):
+                  telemetry=None, **kw):
+    """A simulated cluster: ``n`` FakeModel replicas sharing the host's
+    device on one SimClock (``ServingCluster.build`` would give each
+    replica a device of its own)."""
     clock = clock if clock is not None else SimClock()
     kw.setdefault("max_batch", 4)
     kw.setdefault("max_len", 64)
@@ -41,9 +44,12 @@ def build_cluster(n, policy="cost_aware", clock=None, shed_wait_s=None,
     kw.setdefault("block_size", 8)
     kw.setdefault("chunk_size", 8)
     kw.setdefault("cost_model", FakeCostModel(decode_s=0.5, prefill_s=0.25))
-    cl = ServingCluster.build(FakeModel(vocab=VOCAB), None, n_replicas=n,
-                              policy=policy, clock=clock,
-                              shed_wait_s=shed_wait_s, **kw)
+    replicas = [PagedServingEngine(
+        FakeModel(vocab=VOCAB), None, clock=clock,
+        telemetry=telemetry.controller(i) if telemetry else None, **kw)
+        for i in range(n)]
+    cl = ServingCluster(replicas, policy=policy, shed_wait_s=shed_wait_s,
+                        telemetry=telemetry)
     return cl, clock
 
 
@@ -304,20 +310,30 @@ def test_cluster_telemetry_merge_and_tags(tmp_path):
 
 
 def test_build_from_device_budget_uses_cost_model_topology():
+    import jax
     from repro.configs.base import ShapeCell
     from repro.core.costmodel import CostModel
     from repro.sharding.plans import rank_cluster_topologies
     model = FakeModel(vocab=VOCAB)
     cm = CostModel.from_named("tpu_v5e")
     cell = ShapeCell("t", "decode", 64, 4)
-    cluster = ServingCluster.build(model, None, clock=SimClock(),
-                                   cost_model=cm, n_devices=4, cell=cell,
-                                   max_batch=4, max_len=64, n_blocks=24,
-                                   block_size=8, chunk_size=8)
-    top = rank_cluster_topologies(model.cfg, cell, 4, cm)[0]
+    kw = dict(clock=SimClock(), cost_model=cm, cell=cell, max_batch=4,
+              max_len=64, n_blocks=24, block_size=8, chunk_size=8)
+    n_dev = len(jax.devices())
+    cluster = ServingCluster.build(model, None, n_devices=n_dev, **kw)
+    top = rank_cluster_topologies(model.cfg, cell, n_dev, cm)[0]
     assert cluster.topology is not None
     assert len(cluster.replicas) == top.n_replicas
-    assert cluster.topology.devices_per_replica * top.n_replicas == 4
+    assert cluster.topology.devices_per_replica * top.n_replicas == n_dev
+    # every replica's pool sits on its own slice of the devices
+    placed = [tuple(sorted(d.id for d in eng.cache["k"].devices()))
+              for eng in cluster.replicas]
+    assert len(set(placed)) == len(placed)
+    # a budget beyond the devices present is refused, not simulated
+    with pytest.raises(ValueError, match="exceeds"):
+        ServingCluster.build(model, None, n_devices=4 * n_dev, **kw)
+    with pytest.raises(ValueError, match="exceeds"):
+        ServingCluster.build(model, None, n_replicas=n_dev + 1, **kw)
     with pytest.raises(ValueError):
         ServingCluster.build(model, None)   # neither n_replicas nor budget
 
