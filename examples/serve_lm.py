@@ -29,9 +29,12 @@ def main():
     params = model.init(jax.random.PRNGKey(0))
     cm = CostModel.from_named("tpu_v5e")
     # a tight budget: admissions beyond the first per step defer until the
-    # predicted iteration time (decode + prefills) fits again
+    # predicted iteration time (decode + prefills) fits again; the
+    # controller only records (drift=False: no recalibration)
+    slot_ctl = TelemetryController(drift=False)
     eng = ServingEngine(model, params, max_batch=4, max_len=96,
-                        cost_model=cm, step_budget_s=5e-5)
+                        cost_model=cm, step_budget_s=5e-5,
+                        telemetry=slot_ctl)
 
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, cfg.vocab_size,
@@ -49,10 +52,11 @@ def main():
           f"{stats.deferred_prefills} admissions deferred, "
           f"{stats.host_syncs/max(stats.steps, 1):.2f} host syncs/step "
           "on the fused hot path)")
-    if stats.predicted_step_s:
-        print(f"  predicted step time: {min(stats.predicted_step_s):.2e}-"
-              f"{max(stats.predicted_step_s):.2e}s "
-              f"(measured median {np.median(stats.measured_step_s):.2e}s)")
+    steps = slot_ctl.sink.steps()
+    pred = [s.predicted_s for s in steps]
+    print(f"  predicted step time: {min(pred):.2e}-{max(pred):.2e}s "
+          f"(measured median "
+          f"{np.median([s.measured_s for s in steps]):.2e}s)")
     for rid in rids[:3]:
         r = eng.done[rid]
         print(f"  req {rid}: prompt[{len(r.prompt)}] -> {r.tokens}")
@@ -80,6 +84,9 @@ def main():
     identical = all(eng.done[a].tokens == paged.done[b].tokens
                     for a, b in zip(rids, prids))
     print(f"  greedy tokens identical: {identical}")
+    occ = [r.blocks_in_use / r.n_blocks for r in ctl.sink.steps()]
+    print(f"  block occupancy per step: mean {np.mean(occ):.0%}, "
+          f"peak {max(occ):.0%}")
     s = ctl.sink.summary()
     snap = ctl.sink.save("results/telemetry/serve_lm_snapshot.json")
     print(f"  telemetry: {s['steps']} steps recorded, "

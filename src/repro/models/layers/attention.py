@@ -337,11 +337,14 @@ def attention(p, x, *, cfg, positions, is_global, theta=None,
         # tuned=True: num_splits (the flash-decoding grid axis) resolves
         # from the installed tuning cache at trace time, like the flash
         # path's blocks — the serving engine installs its autotuner
-        # around _step, so long contexts pick their tuned split factor
-        out_h = kops.paged_attention(
-            q[:, 0], new_cache["k"], new_cache["v"], block_tables,
-            write_pos + 1, scale=scale, window=window,
-            softcap=cfg.attn_softcap, tuned=True)[:, None]
+        # around _step, so long contexts pick their tuned split factor.
+        # The scope names the kernel's device ops in a profiler trace
+        # whatever the wrappers inside it are called.
+        with jax.named_scope("paged_attention"):
+            out_h = kops.paged_attention(
+                q[:, 0], new_cache["k"], new_cache["v"], block_tables,
+                write_pos + 1, scale=scale, window=window,
+                softcap=cfg.attn_softcap, tuned=True)[:, None]
     elif use_pallas:
         # TPU hot path: the blocked flash kernel (kernels/flash_attention);
         # ragged sequence tails are padded+masked inside the kernel.

@@ -81,12 +81,14 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.costmodel.model import CostModel, Prediction
 from repro.models.zoo import Model, fused_decode_step
 from repro.serve.paging import (BlockAllocator, blocks_for_tokens,
                                 remap_table)
 from repro.serve.scheduler import ChunkedPrefillScheduler
+from repro.serve.telemetry import spans
 from repro.sharding import ctx
 
 
@@ -99,6 +101,7 @@ class Request:
     # filled by the engine:
     tokens: List[int] = dataclasses.field(default_factory=list)
     submitted_s: float = 0.0
+    first_token_s: Optional[float] = None   # clock.time() at first booking
     finished_s: float = 0.0
 
 
@@ -121,14 +124,11 @@ class EngineStats:
     deferred_prefills: int = 0      # admissions pushed to a later step
     host_syncs: int = 0             # device->host transfers (via _sync)
     table_uploads: int = 0          # block-table host->device uploads
-    predicted_step_s: List[float] = dataclasses.field(default_factory=list)
-    measured_step_s: List[float] = dataclasses.field(default_factory=list)
     # paged-engine extensions (stay 0/empty on the slot engine)
     prefill_chunks: int = 0         # chunked-prefill calls run
     preemptions: int = 0            # evictions (blocks reclaimed, re-enqueued)
     compactions: int = 0            # copy-on-retire block compactions
     peak_blocks_in_use: int = 0
-    block_occupancy: List[float] = dataclasses.field(default_factory=list)
     admission_order: List[int] = dataclasses.field(default_factory=list)
     integrity_failures: int = 0     # corrupted fused-step drains dropped
 
@@ -181,6 +181,7 @@ class _TunedDispatch:
 
     autotuner = None
     telemetry = None
+    _sync_s = 0.0           # seconds this iteration blocked in _sync
 
     def step(self) -> int:
         if self.autotuner is not None:
@@ -192,9 +193,21 @@ class _TunedDispatch:
     def _sync(self, x) -> np.ndarray:
         """THE device->host boundary: every value an engine reads back
         crosses here (explicit ``jax.device_get``, counted), so the
-        transfer-guard test can disallow every other transfer."""
+        transfer-guard test can disallow every other transfer.  The
+        wait is the span ``serve.sync`` and sums into ``_sync_s``."""
         self.stats.host_syncs += 1
-        return np.asarray(jax.device_get(x))
+        t = self._clock.perf_counter()
+        with TraceAnnotation(spans.SYNC):
+            host = jax.device_get(x)
+        self._sync_s += self._clock.perf_counter() - t
+        return np.asarray(host)
+
+    def _book_first_token(self, req: Request, tok: int) -> None:
+        """Append a request's first token; a replay after eviction keeps
+        the time of the first booking."""
+        if req.first_token_s is None:
+            req.first_token_s = self._clock.time()
+        req.tokens.append(tok)
 
     def _step_budget(self) -> Optional[float]:
         """The effective admission budget for this iteration: the SLO
@@ -418,7 +431,7 @@ class ServingEngine(_TunedDispatch):
                 return big.at[:, slot:slot + 1].set(small.astype(big.dtype))
             self.cache = jax.tree.map(splice, self.cache, cache1)
             self.slot_tok[slot] = int(self._sync(jnp.argmax(logits[0])))
-            req.tokens.append(int(self.slot_tok[slot]))
+            self._book_first_token(req, int(self.slot_tok[slot]))
         self.slot_req[slot] = req
         self.slot_pos[slot] = S
         self.stats.prefills += 1
@@ -454,7 +467,7 @@ class ServingEngine(_TunedDispatch):
             if self.slot_req[i] is not req:
                 continue                     # shadow step of a retired row
             if not req.tokens:
-                req.tokens.append(int(in_t[i]))      # prefill's first token
+                self._book_first_token(req, int(in_t[i]))  # prefill's first
             req.tokens.append(int(out_t[i]))
             self.stats.decoded_tokens += 1
             self.slot_pos[i] += 1
@@ -486,7 +499,8 @@ class ServingEngine(_TunedDispatch):
             decoded_tokens=self.stats.decoded_tokens,
             preemptions=0, deferred=self.stats.deferred_prefills,
             kernel_splits=0,
-            integrity_failures=self.stats.integrity_failures)
+            integrity_failures=self.stats.integrity_failures,
+            sync_s=self._sync_s)
 
     def _step(self) -> int:
         """One engine iteration.  Returns #active at dispatch time.
@@ -498,7 +512,7 @@ class ServingEngine(_TunedDispatch):
         tokens always happens after the NEXT step is on the device."""
         if not self.fused:
             return self._step_blocking()
-        t0 = self._clock.perf_counter()
+        t0, self._sync_s = self._clock.perf_counter(), 0.0
         prev, self._pending = self._pending, None
         planned, admitted, budget = self._admit()
         active = [i for i, r in enumerate(self.slot_req) if r is not None]
@@ -510,9 +524,6 @@ class ServingEngine(_TunedDispatch):
             self.stats.steps += 1
         self._drain(prev)
         measured = self._clock.perf_counter() - t0
-        if active and self.cost_model is not None:
-            self.stats.predicted_step_s.append(planned)
-            self.stats.measured_step_s.append(measured)
         if active and self.telemetry is not None:
             self.telemetry.on_step(self._step_record(
                 planned, measured, len(active), admitted, budget))
@@ -521,7 +532,7 @@ class ServingEngine(_TunedDispatch):
     def _step_blocking(self) -> int:
         """The legacy (unfused) iteration: fresh uploads, the [B, vocab]
         logits synced, undonated cache — the decode_hotpath baseline."""
-        t0 = self._clock.perf_counter()
+        t0, self._sync_s = self._clock.perf_counter(), 0.0
         planned, admitted, budget = self._admit()
         active = [i for i, r in enumerate(self.slot_req) if r is not None]
         if not active:
@@ -532,9 +543,6 @@ class ServingEngine(_TunedDispatch):
         nxt = self._sync(jnp.argmax(logits, axis=-1)).astype(np.int32)
         self.stats.steps += 1
         measured = self._clock.perf_counter() - t0
-        if self.cost_model is not None:
-            self.stats.predicted_step_s.append(planned)
-            self.stats.measured_step_s.append(measured)
         if self.telemetry is not None:
             self.telemetry.on_step(self._step_record(
                 planned, measured, len(active), admitted, budget))
@@ -973,6 +981,7 @@ class PagedServingEngine(_TunedDispatch):
         self.stats.admission_order.append(req.rid)
         return idx
 
+    @spans.traced(spans.CHUNK)
     def _run_chunk(self, idx: int) -> None:
         """Advance row ``idx``'s prefill by one chunk.
 
@@ -999,16 +1008,20 @@ class PagedServingEngine(_TunedDispatch):
         toks = np.zeros(C, np.int32)
         lo = max(start, 0)
         toks[C - (end - lo):] = req.prompt[lo:end]
-        bt = self._bt_device()[idx:idx + 1]
-        if self.fused:
-            self.cache, self._toks = self._chunk(
-                self.params, self.cache, self._dev(toks[None]),
-                self._dev(np.asarray([start], np.int32)), bt, self._toks,
-                self._dev(np.int32(idx)), self._dev(end == S))
-        else:
-            logits, self.cache = self._chunk(
-                self.params, self.cache, jnp.asarray(toks[None]),
-                jnp.asarray([start], jnp.int32), bt)
+        with TraceAnnotation(spans.UPLOAD):
+            bt = self._bt_device()
+            head = (self._dev(toks[None]),
+                    self._dev(np.asarray([start], np.int32)))
+            if self.fused:
+                tail = (self._dev(np.int32(idx)), self._dev(end == S))
+        with TraceAnnotation(spans.LAUNCH):
+            bt = bt[idx:idx + 1]      # a program of its own on the device
+            if self.fused:
+                self.cache, self._toks = self._chunk(
+                    self.params, self.cache, *head, bt, self._toks, *tail)
+            else:
+                logits, self.cache = self._chunk(
+                    self.params, self.cache, *head, bt)
         row.filled = end
         self.stats.prefill_chunks += 1
         if end == S:
@@ -1017,18 +1030,27 @@ class PagedServingEngine(_TunedDispatch):
             self.stats.prefills += 1
             if not self.fused:
                 row.last_tok = int(self._sync(jnp.argmax(logits[0])))
-                req.tokens.append(row.last_tok)
+                self._book_first_token(req, row.last_tok)
 
     # -- the engine iteration -------------------------------------------------
     def _step(self) -> int:
-        """One iteration: plan, run prefill chunks, dispatch the decode,
-        then drain the PREVIOUS step (fused) — so step N's tokens are
-        synced only after step N+1 is on the device, and retire/admit/
-        schedule bookkeeping runs in the device step's shadow.  Returns
-        the number of placed rows (>= 1 while a step is still in
-        flight).  (``step()`` is the inherited autotuner-installing
-        shell.)"""
-        t0 = self._clock.perf_counter()
+        """One iteration inside the span ``serve.step``, which ends
+        with the iteration's counts (``telemetry.spans``).  Returns the
+        number of placed rows (>= 1 while a step is still in flight).
+        (``step()`` is the inherited autotuner-installing shell.)"""
+        with TraceAnnotation(spans.STEP) as span:
+            n, rows = self._iterate()
+            if span.is_enabled():
+                span.set_metadata(rows=rows)
+        return n
+
+    def _iterate(self) -> "tuple[int, int]":
+        """Plan, run prefill chunks, dispatch the decode, then drain
+        the PREVIOUS step (fused) — so step N's tokens are synced only
+        after step N+1 is on the device, and retire/admit/schedule
+        bookkeeping runs in the device step's shadow.  Returns ``_step``'s
+        row count and the rows the decode stepped."""
+        t0, self._sync_s = self._clock.perf_counter(), 0.0
         prev, self._pending = self._pending, None
         unfinished = sorted(
             ((i, self.rows[i].req.rid, self.rows[i].req)
@@ -1038,18 +1060,19 @@ class PagedServingEngine(_TunedDispatch):
         any_ready = any(r is not None and r.ready for r in self.rows)
         if not unfinished and not any_ready and not self.scheduler.queue:
             self._drain(prev)        # flush the tail step, if any
-            return 0
-        budget = self._step_budget()
-        gated = self.cost_model is not None and budget is not None
-        decode_s = self._predict_decode().step_s \
-            if self.cost_model is not None else 0.0
-        chunk_s = self._predict_chunk().step_s \
-            if self.cost_model is not None else 0.0
+            return 0, 0
         chunks_before = self.stats.prefill_chunks
-        plan = self.scheduler.plan(
-            unfinished=unfinished, n_free_rows=n_free, any_ready=any_ready,
-            decode_s=decode_s, chunk_s=chunk_s, gated=gated,
-            budget_s=budget)
+        with TraceAnnotation(spans.PLAN):
+            budget = self._step_budget()
+            gated = self.cost_model is not None and budget is not None
+            decode_s = self._predict_decode().step_s \
+                if self.cost_model is not None else 0.0
+            chunk_s = self._predict_chunk().step_s \
+                if self.cost_model is not None else 0.0
+            plan = self.scheduler.plan(
+                unfinished=unfinished, n_free_rows=n_free,
+                any_ready=any_ready, decode_s=decode_s, chunk_s=chunk_s,
+                gated=gated, budget_s=budget)
         self.stats.deferred_prefills += plan.deferred
 
         for item in plan.items:
@@ -1070,25 +1093,19 @@ class PagedServingEngine(_TunedDispatch):
         # a block AND retire within one _decode_phase; sampling n_in_use
         # here would miss that high-water mark)
         self.stats.peak_blocks_in_use = self.allocator.peak_in_use
+        # an iteration can dispatch nothing when its only ready rows are
+        # retirement-bound in the pending drain: it does not count
         did_work = bool(plan.items) or active
-        if did_work:
-            # sampled iff the step counts, so occupancy and steps stay
-            # one-to-one (an iteration can dispatch nothing when its only
-            # ready rows are retirement-bound in the pending drain)
-            self.stats.block_occupancy.append(self.allocator.occupancy)
         self._drain(prev)
+        chunks = self.stats.prefill_chunks - chunks_before
         if did_work:
             self.stats.steps += 1
             measured = self._clock.perf_counter() - t0
-            if self.cost_model is not None:
-                self.stats.predicted_step_s.append(plan.predicted_s)
-                self.stats.measured_step_s.append(measured)
             if self.telemetry is not None:
                 self.telemetry.on_step(self._step_record(
-                    plan.predicted_s, measured, active,
-                    self.stats.prefill_chunks - chunks_before, budget))
+                    plan.predicted_s, measured, active, chunks, budget))
         n = len(self._placed())
-        return n if self._pending is None else max(n, 1)
+        return (n if self._pending is None else max(n, 1)), active
 
     def _step_record(self, planned: float, measured: float,
                      n_decoded_rows: int, n_chunks: int,
@@ -1116,8 +1133,10 @@ class PagedServingEngine(_TunedDispatch):
             preemptions=self.stats.preemptions,
             deferred=self.stats.deferred_prefills,
             kernel_splits=self.kernel_splits,
-            integrity_failures=self.stats.integrity_failures)
+            integrity_failures=self.stats.integrity_failures,
+            sync_s=self._sync_s)
 
+    @spans.traced(spans.DECODE)
     def _decode_phase(self) -> int:
         """Batched decode over the ready rows; rows mid-prefill (or whose
         block growth must wait) ride along masked out via write_pos=-1."""
@@ -1150,9 +1169,11 @@ class PagedServingEngine(_TunedDispatch):
         for i, row in stepping:
             pos[i] = row.pos
         if self.fused:
-            io, self._toks, self.cache = self._decode(
-                self.params, self.cache, self._toks,
-                self._dev(pos, "batch"), self._bt_device())
+            with TraceAnnotation(spans.UPLOAD):
+                pos_d, bt = self._dev(pos, "batch"), self._bt_device()
+            with TraceAnnotation(spans.LAUNCH):
+                io, self._toks, self.cache = self._decode(
+                    self.params, self.cache, self._toks, pos_d, bt)
             # the snapshot carries each row's post-step position: that is
             # the value retire checks compare against at drain time
             # (row.pos itself may advance again before the drain)
@@ -1165,9 +1186,10 @@ class PagedServingEngine(_TunedDispatch):
         toks = np.zeros((self.max_batch, 1), np.int32)
         for i, row in stepping:
             toks[i, 0] = row.last_tok
-        logits, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(toks), jnp.asarray(pos),
-            self._bt_device())
+        with TraceAnnotation(spans.UPLOAD):
+            args = (jnp.asarray(toks), jnp.asarray(pos), self._bt_device())
+        with TraceAnnotation(spans.LAUNCH):
+            logits, self.cache = self._decode(self.params, self.cache, *args)
         nxt = self._sync(jnp.argmax(logits, axis=-1)).astype(np.int32)
         for i, row in stepping:
             req = row.req
@@ -1199,7 +1221,7 @@ class PagedServingEngine(_TunedDispatch):
                 continue
             req = row.req
             if not req.tokens:
-                req.tokens.append(int(in_t[i]))      # echoed prefill token
+                self._book_first_token(req, int(in_t[i]))  # echoed prefill
             req.tokens.append(int(out_t[i]))
             self.stats.decoded_tokens += 1
             hit_eos = req.eos_id is not None and req.tokens[-1] == req.eos_id
@@ -1208,6 +1230,7 @@ class PagedServingEngine(_TunedDispatch):
             if hit_eos or out_of_budget or out_of_cache:
                 self._retire(i)
 
+    @spans.traced(spans.RETIRE)
     def _retire(self, idx: int) -> None:
         req = self.rows[idx].req
         req.finished_s = self._clock.time()

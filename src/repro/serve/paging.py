@@ -56,10 +56,6 @@ class BlockAllocator:
     def n_in_use(self) -> int:
         return len(self._allocated)
 
-    @property
-    def occupancy(self) -> float:
-        return self.n_in_use / self.n_blocks
-
     def alloc(self) -> Optional[int]:
         """Lowest free block id, or None when the pool is exhausted."""
         if not self._free:

@@ -157,7 +157,8 @@ class TelemetryController:
             engine=self.engine_name, rid=req.rid,
             submitted_s=req.submitted_s, finished_s=req.finished_s,
             latency_s=req.finished_s - req.submitted_s,
-            prompt_len=len(req.prompt), n_tokens=len(req.tokens)))
+            prompt_len=len(req.prompt), n_tokens=len(req.tokens),
+            first_token_s=req.first_token_s))
 
     # ----- drift -> recalibration --------------------------------------------
 

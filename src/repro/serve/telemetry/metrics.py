@@ -84,6 +84,7 @@ class StepRecord:
     deferred: int               # cumulative budget-deferred admissions
     kernel_splits: int          # tuned split-KV factor (paged; 0 slot)
     integrity_failures: int = 0  # cumulative corrupted-step drains dropped
+    sync_s: float = 0.0         # seconds blocked in _sync this iteration
 
 
 @dataclasses.dataclass
@@ -96,6 +97,8 @@ class RequestRecord:
     latency_s: float            # finished - submitted
     prompt_len: int             # prompt tokens
     n_tokens: int               # generated tokens delivered
+    # clock.time() at the first token's booking; None: no token yet
+    first_token_s: Optional[float] = None
 
 
 def _fields(cls, meta: Dict[str, Tuple[str, str, str]]) -> List[Field]:
@@ -106,7 +109,9 @@ def _fields(cls, meta: Dict[str, Tuple[str, str, str]]) -> List[Field]:
     for f in dataclasses.fields(cls):
         unit, engines, desc = meta[f.name]
         out.append(Field(f.name, f.type if isinstance(f.type, str)
-                         else f.type.__name__, unit, engines, desc))
+                         else f.type.__name__ if isinstance(f.type, type)
+                         else str(f.type).replace("typing.", ""),
+                         unit, engines, desc))
     return out
 
 
@@ -143,6 +148,10 @@ _STEP_META = {
     "integrity_failures": ("count", "both",
                            "cumulative fused-step drains dropped by the "
                            "token-echo integrity probe (0 healthy)"),
+    "sync_s": ("s", "both",
+               "seconds this iteration blocked in _sync waiting for the "
+               "device (the serve.sync spans); measured_s - sync_s is "
+               "host work plus any wait in launches (serve.launch)"),
 }
 _REQUEST_META = {
     "engine": ("-", "both", "emitting engine: 'slot' or 'paged'"),
@@ -152,6 +161,11 @@ _REQUEST_META = {
     "latency_s": ("s", "both", "finished_s - submitted_s"),
     "prompt_len": ("tokens", "both", "prompt tokens"),
     "n_tokens": ("tokens", "both", "generated tokens delivered"),
+    "first_token_s": ("s", "both",
+                      "clock.time() when the first token was booked (a "
+                      "replay after eviction keeps the first), None if "
+                      "retired before one; TTFT is first_token_s - "
+                      "submitted_s"),
 }
 
 STEP_FIELDS: List[Field] = _fields(StepRecord, _STEP_META)

@@ -11,6 +11,7 @@ from repro.core.costmodel import CostModel
 from repro.launch.mesh import make_host_mesh
 from repro.models.zoo import build_model
 from repro.serve.engine import ServingEngine
+from repro.serve.telemetry import TelemetryController
 from repro.train.loop import train
 
 
@@ -65,16 +66,18 @@ def test_engine_cost_model_admission_defers_but_completes(tiny_lm):
     request with the same greedy tokens."""
     cfg, model, params = tiny_lm
     cm = CostModel.from_named("tpu_v5e")
+    ctl = TelemetryController(drift=False)      # records only
     eng = ServingEngine(model, params, max_batch=4, max_len=48,
-                        cost_model=cm, step_budget_s=0.0)
+                        cost_model=cm, step_budget_s=0.0, telemetry=ctl)
     prompts = [np.arange(3 + i, dtype=np.int32) % cfg.vocab_size
                for i in range(6)]
     rids = [eng.submit(p, max_new_tokens=4) for p in prompts]
     stats = eng.run_until_done()
     assert stats.completed == 6
     assert stats.deferred_prefills > 0          # the budget actually gated
-    assert len(stats.predicted_step_s) == stats.steps
-    assert all(s > 0 for s in stats.predicted_step_s)
+    predicted = [r.predicted_s for r in ctl.sink.steps()]
+    assert len(predicted) == stats.steps
+    assert all(s > 0 for s in predicted)
     for rid, p in zip(rids, prompts):
         want = _greedy_reference(model, params, jnp.asarray(p), 4, 48)
         assert eng.done[rid].tokens == want

@@ -19,6 +19,7 @@ from repro.models.zoo import build_model
 from repro.serve import PagedServingEngine, ServingEngine
 from repro.serve.sim import (FakeCostModel, FakeModel, SimClock, drive,
                              expected_tokens)
+from repro.serve.telemetry import TelemetryController
 
 
 def paged(model, clock=None, **kw):
@@ -87,9 +88,10 @@ def test_deferred_prefills_exact_accounting():
     in each of the first two planning steps and nothing afterwards."""
     model = FakeModel()
     clock = SimClock()
+    ctl = TelemetryController(drift=False)      # records only
     eng = paged(model, clock, chunk_size=4,
                 cost_model=FakeCostModel(decode_s=1.0, prefill_s=1.0),
-                step_budget_s=2.5)
+                step_budget_s=2.5, telemetry=ctl)
     prompts = [list(range(10, 18)), list(range(30, 38)),
                list(range(50, 58))]           # 8 tokens = 2 chunks each
     for p in prompts:
@@ -107,7 +109,7 @@ def test_deferred_prefills_exact_accounting():
     eng.run_until_done()
     assert eng.stats.completed == 3
     assert eng.stats.deferred_prefills == 2   # nothing counted after
-    assert eng.stats.predicted_step_s[:3] == [2.0, 2.0, 2.0]
+    assert [r.predicted_s for r in ctl.sink.steps()][:3] == [2.0, 2.0, 2.0]
     for rid, req in eng.done.items():
         assert req.tokens == expected_tokens(req.prompt, 3, 97)
 
@@ -184,12 +186,14 @@ def test_overlong_prompts_rejected_at_submit(tiny_lm):
 def test_block_occupancy_stats_tracked():
     model = FakeModel()
     clock = SimClock()
-    eng = paged(model, clock)
+    ctl = TelemetryController(drift=False)
+    eng = paged(model, clock, telemetry=ctl)
     drive(eng, clock, [(0.0, [3, 4, 5, 6, 7], 4, None)])
     assert eng.stats.peak_blocks_in_use >= 2
-    assert len(eng.stats.block_occupancy) == eng.stats.steps
-    assert all(0.0 <= o <= 1.0 for o in eng.stats.block_occupancy)
-    assert max(eng.stats.block_occupancy) > 0
+    occupancy = [r.blocks_in_use / r.n_blocks for r in ctl.sink.steps()]
+    assert len(occupancy) == eng.stats.steps
+    assert all(0.0 <= o <= 1.0 for o in occupancy)
+    assert max(occupancy) > 0
 
 
 def test_fused_path_skips_predictable_shadow_steps():

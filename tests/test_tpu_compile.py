@@ -106,8 +106,9 @@ def test_paged_attention_compiles_for_v5e(one_chip, hbm, num_splits):
 
 def test_paged_decode_step_compiles_for_v5e_and_fits(one_chip, on_tpu):
     """The engine's fused, donated decode step at the smoke's depth: the
-    paged kernel is in its HLO (not the jnp gather) and the program
-    fits one chip's 16 GiB."""
+    paged kernel is in its HLO (not the jnp gather), named after its
+    jitted wrapper and under the ``paged_attention`` scope, and the
+    program fits one chip's 16 GiB."""
     model = _smoke_model()
     B, NB = SMOKE.N_REQUESTS, -(-SMOKE.MAX_LEN // SMOKE.BLOCK)
     params = _placed(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
@@ -118,7 +119,12 @@ def test_paged_decode_step_compiles_for_v5e_and_fits(one_chip, on_tpu):
     compiled = jax.jit(paged_decode_fn(model.decode_step),
                        donate_argnums=(1,)).lower(
         params, pool, i32(B), i32(B), i32(B, NB)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    kernels = [ln for ln in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert kernels
+    for ln in kernels:
+        assert ln.strip().startswith("%_pa_jit")
+        assert "/paged_attention/jit(_pa_jit)/pallas_call" in ln
     assert _total_bytes(compiled) <= V5E_HBM_BYTES
 
 
