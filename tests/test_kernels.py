@@ -236,6 +236,48 @@ def test_paged_attention_zero_context_rows_are_zero():
     np.testing.assert_allclose(np.asarray(o), np.asarray(r), atol=1e-5)
 
 
+def _grouped_case(H, KH):
+    """Rows of context 1, 130, 300 and 0 over 16-token pages, so spans of
+    128 tokens are crossed and end mid-span and mid-page, with a -1
+    entry inside the 300 row's second span.  Each KV head carries its
+    own offset, so a query head that attends another head's group
+    reads the wrong values."""
+    q, kp, vp, bt, ctx = _paged_case(4, H, KH, 16, 16, (1, 130, 300, 0),
+                                     n_pages=32, seed=KH)
+    head = jnp.arange(KH, dtype=jnp.float32)[None, None, :, None]
+    return q, kp + 0.5 * head, vp + head, bt.at[2, 10].set(-1), ctx
+
+
+@pytest.mark.parametrize("num_splits", [1, 3])
+@pytest.mark.parametrize("hbm", [False, True])
+@pytest.mark.parametrize("H,KH", [(12, 2), (8, 8), (4, 1)],
+                         ids=["gqa", "mha", "mqa"])
+def test_paged_attention_all_heads_of_a_row_per_cell(H, KH, hbm,
+                                                     num_splits):
+    """One grid cell serves every query head of its row, scoring each
+    span against all KV heads at once and masking other groups'
+    columns: GQA, MHA and MQA groupings, both lowerings, unsplit and
+    split, against the oracle."""
+    q, kp, vp, bt, ctx = _grouped_case(H, KH)
+    o = ops.paged_attention(q, kp, vp, bt, ctx, hbm=hbm,
+                            num_splits=num_splits)
+    r = ref.paged_attention_ref(q, kp, vp, bt, ctx)
+    assert np.abs(np.asarray(o)[3]).max() == 0.0
+    np.testing.assert_allclose(np.asarray(o), np.asarray(r), atol=1e-5,
+                               rtol=1e-5)
+
+
+def test_paged_attention_all_heads_window_and_softcap():
+    """The window and softcap rules over spans, per query head."""
+    q, kp, vp, bt, ctx = _grouped_case(12, 2)
+    kw = dict(window=150, softcap=4.0)
+    o = ops.paged_attention(q, kp, vp, bt, ctx, hbm=True, num_splits=3,
+                            **kw)
+    r = ref.paged_attention_ref(q, kp, vp, bt, ctx, **kw)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(r), atol=1e-5,
+                               rtol=1e-5)
+
+
 # ---------------------------------------------------------------------------
 # split-KV flash decoding: every split factor must be invisible to the caller
 # ---------------------------------------------------------------------------

@@ -13,6 +13,7 @@ library, and every test worker imports this file.
 """
 import dataclasses
 import importlib.util
+import json
 import os
 from pathlib import Path
 
@@ -83,25 +84,45 @@ def _total_bytes(compiled):
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
+def _cell_engine():
+    """The chat cell's engine: its decode batch, pool and table width."""
+    path = (Path(__file__).resolve().parents[1] / "bench" / "workloads"
+            / "internlm2-20b.chat.json")
+    return json.loads(path.read_text())["engine"]
+
+
+CELL = _cell_engine()
+
+
 @pytest.mark.parametrize("num_splits", [1, 4])
-@pytest.mark.parametrize("hbm", [False, True], ids=["staged", "hbm"])
-def test_paged_attention_compiles_for_v5e(one_chip, hbm, num_splits):
+@pytest.mark.parametrize("lowering", ["staged", "hbm", "hbm-cell"])
+def test_paged_attention_compiles_for_v5e(one_chip, lowering, num_splits):
     """Both lowerings at internlm2-20b widths (48 heads over 8 KV heads
     of 128, bf16).  The staged form stages the whole pool into VMEM, so
-    it gets a small pool; the HBM form gets the smoke's."""
+    it gets a small pool; the HBM form gets the smoke's and the
+    benchmark cells' pool, which the kernel reads where it lies: no
+    temporary as large as the pool, so no copy or relayout of it."""
     from repro.kernels.paged_attention import (paged_attention,
                                                paged_attention_hbm)
     cfg = get_config(SMOKE.ARCH)
-    B, H, KH, D = SMOKE.N_REQUESTS, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    NB = -(-SMOKE.MAX_LEN // SMOKE.BLOCK)
-    P = B * NB if hbm else 64
+    H, KH, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    if lowering == "hbm-cell":
+        B, bs, P = CELL["max_batch"], CELL["block_size"], CELL["n_blocks"]
+        NB = -(-CELL["max_len"] // bs)
+    else:
+        B, bs = SMOKE.N_REQUESTS, SMOKE.BLOCK
+        NB = -(-SMOKE.MAX_LEN // bs)
+        P = B * NB if lowering == "hbm" else 64
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
-    pool = S((P, SMOKE.BLOCK, KH, D), jnp.bfloat16)
-    kern = paged_attention_hbm if hbm else paged_attention
+    pool = S((P, bs, KH, D), jnp.bfloat16)
+    kern = paged_attention if lowering == "staged" else paged_attention_hbm
     compiled = jax.jit(lambda *a: kern(*a, num_splits=num_splits)).lower(
         S((B, H, D), jnp.bfloat16), pool, pool, S((B, NB), jnp.int32),
         S((B,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    if lowering != "staged":
+        pool_bytes = P * bs * KH * D * 2
+        assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
 
 def test_paged_decode_step_compiles_for_v5e_and_fits(one_chip, on_tpu):
