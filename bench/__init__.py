@@ -6,6 +6,9 @@ configuration, one traffic mix, one cell or one metric lives in a file
 of its own, found by name:
 
 * ``configs/<config>.json``   model sizes as run, the source, the cut;
+* ``references/<name>.py``   the plain reference a configuration names:
+  one family's sizes, weights, forward pass, flop count and the
+  program's layout of its weights;
 * ``traffic/<mix>.json``      a traffic mix: parameters of a generator;
 * ``traffic/<generator>.py``  a seeded open-loop generator;
 * ``workloads/<cell>.json``   configuration + mix + rate + engine sizes;

@@ -2,11 +2,11 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the finished requests, drawn from the seed and always holding the
-longest, goes through the float32 reference (``bench.reference``), one
-whole sequence at a time: its prompt followed by the tokens the engine
-served.  At each served position the reference's best logit minus its
-logit of the served token is a gap; greedy decoding at the stated
-precision keeps it near 0.  Compared numbers:
+longest, goes through the float32 reference that the configuration
+names (``bench/references/<name>.py``), one whole sequence at a time:
+its prompt followed by the tokens the engine served.  At each served
+position the reference's best logit minus its logit of the served token
+is a gap; greedy decoding at the stated precision keeps it near 0.  Compared numbers:
 
 * ``max_logit_gap``: the widest gap over the sample, against the cell's
   limit (set from sound runs and the fp8 control, see ``PERF.md``);
@@ -22,7 +22,7 @@ import sys
 import jax
 import numpy as np
 
-from bench.reference import served_gaps, weights
+from bench.reference import served_gaps
 
 BLOCK = 512                 # reference query rows per attention block
 
@@ -66,12 +66,13 @@ def seq_len(max_len: int) -> int:
     return int(math.ceil(max_len / BLOCK) * BLOCK)
 
 
-def gaps(picked, key, dims, max_len: int, max_out: int,
+def gaps(picked, key, ref, dims, max_len: int, max_out: int,
          control: bool = False):
-    """Widest gap over ``picked`` (and the fp8 control's, when asked)."""
+    """Widest gap over ``picked`` (and the fp8 control's, when asked)
+    against the reference module ``ref``."""
     if not picked:
         return None, None
-    w = jax.jit(weights, static_argnums=1)(key, dims)
+    w = jax.jit(ref.weights, static_argnums=1)(key, dims)
     T = seq_len(max_len)
     worst, worst_c = 0.0, 0.0
     every, every_c = [], []
@@ -84,8 +85,8 @@ def gaps(picked, key, dims, max_len: int, max_out: int,
         at = np.minimum(P - 1 + np.arange(max_out), T - 1).astype(np.int32)
         nxt = np.zeros(max_out, np.int32)
         nxt[:n] = served
-        g, gc = served_gaps(w, toks, at, nxt, dims=dims, block=BLOCK,
-                            control=control)
+        g, gc = served_gaps(w, toks, at, nxt, hidden=ref.hidden, dims=dims,
+                            block=BLOCK, control=control)
         every.extend(np.asarray(g)[:n].tolist())
         every_c.extend(np.asarray(gc)[:n].tolist())
         worst = max(worst, max(every[-n:]))

@@ -20,7 +20,7 @@ from typing import Callable, Optional
 import numpy as np
 
 from bench import check, spec
-from bench.reference import Dims, seed_key
+from bench.reference import seed_key
 from bench.serve_loop import Records, drive, warm_up
 from bench.trace import TraceSummary
 
@@ -33,7 +33,7 @@ class NoChip(RuntimeError):
 class RunData:
     """What the metric readers read."""
     cell: spec.Cell
-    dims: Dims
+    dims: object                # the ``Dims`` of the cell's reference
     peaks: Optional[dict]
     chips: int
     setup_s: float
@@ -83,7 +83,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     and returns the model the engine serves.  With ``control`` the fp8
     control (``bench/reference.py``) takes the program's place in the
     comparison that decides ``correct``, on the same prompts and served
-    tokens; the program's own gap is returned beside the checks."""
+    tokens; the program's own gap is returned beside the checks.  The
+    reference is the module the cell's configuration names."""
     import jax
 
     from bench import program
@@ -94,16 +95,14 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     peaks = spec.load_peaks(devs[0].device_kind) if require_tpu else None
     compiles = CompileCount()
 
-    dims = Dims.from_model(cell.config["model"])
-    model = program.build(cell.config)
-    served_model = fault(model) if fault is not None else model
     key = seed_key(seed)
-    params = program.make_params(model, key, dims)
+    dims, model, params = program.load(cell, key)
+    served_model = fault(model) if fault is not None else model
     engine = program.make_engine(served_model, params, eng_cfg)
     rng = np.random.default_rng([int(seed), 1])
     warm_up(engine, dims.vocab, eng_cfg["chunk_size"], rng)
 
-    gen = spec.generator(cell.mix["generator"])
+    gen = spec.generator(cell.mix["generator"], cell.bench)
     lead, grace = float(wl["lead_s"]), float(wl["grace_s"])
     sched = gen.generate(cell.mix, float(wl["rate_rps"]),
                          lead + seconds + grace, seed)
@@ -146,7 +145,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     run = RunData(cell, dims, peaks, chips, setup_s, records, summary)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
-        v = spec.metric_reader(m["name"]).read(run)
+        v = spec.metric_reader(m["name"], cell.bench).read(run)
         if v is not None:
             metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
 
@@ -169,7 +168,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     picked = check.sample(records, seed, lim["sample_tokens"],
                           lim["sample_requests"])
     t = time.perf_counter()
-    gap, gap_c = check.gaps(picked, key, dims, eng_cfg["max_len"],
+    gap, gap_c = check.gaps(picked, key, cell.reference, dims,
+                            eng_cfg["max_len"],
                             int(cell.mix["output_tokens"]["max"]), control)
     log(f"reference over {len(picked)} requests, "
         f"{sum(r.out_len for r in picked)} served tokens: "

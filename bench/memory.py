@@ -49,7 +49,7 @@ def main(argv=None) -> int:
                                        topology_name="v5e:2x2").devices[0]
     one = SingleDeviceSharding(dev)
     jax.default_backend = lambda: "tpu"     # trace the chip's path
-    model = program.build(cell.config)
+    model = program.build(cell.config, cell.reference)
     B, C, bs = eng["max_batch"], eng["chunk_size"], eng["block_size"]
     NB = -(-eng["max_len"] // bs)
 

@@ -1,16 +1,11 @@
-"""The plain reference: a llama-style dense GQA decoder in float32, and
-the weights that both it and the program under test are given.
-
-It follows the published description of InternLM2 and Yi (RMSNorm with
-a weight, rotary embeddings on the split halves of each head, grouped
-query attention, a SwiGLU MLP, untied embedding and head) and imports
-nothing of the program: it runs one whole sequence at a time, with no
-cache, no paging and no batching, every matmul at ``Precision.HIGHEST``.
-
-Weights come from the seed alone.  ``weight(key, name, layer, dims)``
-makes one leaf; the program's copy (``bench.program.program_params``)
-calls the same function, so the two agree without the reference ever
-reading what the program holds.
+"""What every plain reference shares: the seeded key, the fp8 control's
+rounding, ``mm`` (every matmul at ``Precision.HIGHEST``), RMSNorm with a
+weight, rotary embeddings on the split halves of each head, causal
+grouped-query attention over one whole sequence, and ``served_gaps``,
+which compares the served tokens with a family's forward pass.  A
+family's layers, weights and the program's layout of them are in its
+own module, ``bench/references/<name>.py``, which a configuration file
+names.  Nothing here imports the program.
 
 The control (``quant="fp8"``) is the same forward with every matmul's
 two inputs rounded to float8 e4m3 (per-tensor scale, saturating at 448)
@@ -19,7 +14,6 @@ the configurations state.
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 
 import jax
@@ -28,81 +22,12 @@ import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
-# fixed per-leaf stream ids: a leaf's values depend on the seed, its
-# name and its layer, never on the order leaves are made in
-LEAF_IDS = {"embed": 1, "unembed": 2, "ln_f": 3, "ln1": 4, "ln2": 5,
-            "wq": 6, "wk": 7, "wv": 8, "wo": 9, "w_gate": 10, "w_up": 11,
-            "w_down": 12}
-LAYER_LEAVES = ("ln1", "ln2", "wq", "wk", "wv", "wo", "w_gate", "w_up",
-                "w_down")
-
-
-@dataclasses.dataclass(frozen=True)
-class Dims:
-    n_layers: int
-    d_model: int
-    n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    rope_theta: float
-    norm_eps: float
-
-    @classmethod
-    def from_model(cls, model: dict) -> "Dims":
-        """From a configuration file's ``model`` block (HF key names)."""
-        return cls(
-            n_layers=model["num_hidden_layers"],
-            d_model=model["hidden_size"],
-            n_heads=model["num_attention_heads"],
-            n_kv_heads=model["num_key_value_heads"],
-            head_dim=model["hidden_size"] // model["num_attention_heads"],
-            d_ff=model["intermediate_size"],
-            vocab=model["vocab_size"],
-            rope_theta=float(model["rope_theta"]),
-            norm_eps=float(model["rms_norm_eps"]))
-
 
 def seed_key(seed: int):
     """A PRNG key from any whole number, however large."""
     words = np.random.SeedSequence(int(seed)).generate_state(2, np.uint32)
     return jax.random.wrap_key_data(jnp.asarray(words, jnp.uint32),
                                     impl="threefry2x32")
-
-
-def leaf_shape(name: str, dims: Dims):
-    d, h, kh, hd, f, v = (dims.d_model, dims.n_heads, dims.n_kv_heads,
-                          dims.head_dim, dims.d_ff, dims.vocab)
-    return {"embed": (v, d), "unembed": (d, v), "ln_f": (d,), "ln1": (d,),
-            "ln2": (d,), "wq": (d, h, hd), "wk": (d, kh, hd),
-            "wv": (d, kh, hd), "wo": (h, hd, d), "w_gate": (d, f),
-            "w_up": (d, f), "w_down": (f, d)}[name]
-
-
-def weight(key, name: str, layer, dims: Dims):
-    """One float32 leaf.  Embedding and head are N(0, 0.02^2); RMSNorm
-    weights 1 + N(0, 0.1^2), so a norm that drops its weight shows;
-    projections uniform in +-1/sqrt(fan_in).  ``layer`` may be traced
-    (the program's copy makes its stacked layers under ``vmap``)."""
-    k = jax.random.fold_in(jax.random.fold_in(key, LEAF_IDS[name]), layer)
-    shape = leaf_shape(name, dims)
-    if name in ("embed", "unembed"):
-        return 0.02 * jax.random.normal(k, shape, jnp.float32)
-    if name.startswith("ln"):
-        return 1.0 + 0.1 * jax.random.normal(k, shape, jnp.float32)
-    fan_in = {"wo": dims.n_heads * dims.head_dim,
-              "w_down": dims.d_ff}.get(name, dims.d_model)
-    lim = fan_in ** -0.5
-    return jax.random.uniform(k, shape, jnp.float32, -lim, lim)
-
-
-def weights(key, dims: Dims) -> dict:
-    """Every leaf, layers as a list (call under ``jax.jit``)."""
-    top = {n: weight(key, n, 0, dims) for n in ("embed", "unembed", "ln_f")}
-    top["layers"] = [{n: weight(key, n, i, dims) for n in LAYER_LEAVES}
-                     for i in range(dims.n_layers)]
-    return top
 
 
 # ---------------------------------------------------------------------------
@@ -164,32 +89,16 @@ def attention(q, k, v, block: int, quant=None):
     return out.reshape(T, H, hd)
 
 
-def hidden(w, tokens, dims: Dims, block: int, quant=None):
-    """Final-normed hidden states [T, d] of one sequence."""
-    pos = jnp.arange(tokens.shape[0])
-    x = w["embed"][tokens]
-    for lw in w["layers"]:
-        h = rmsnorm(x, lw["ln1"], dims.norm_eps)
-        q = rope(mm("td,dhk->thk", h, lw["wq"], quant), pos, dims.rope_theta)
-        k = rope(mm("td,dhk->thk", h, lw["wk"], quant), pos, dims.rope_theta)
-        v = mm("td,dhk->thk", h, lw["wv"], quant)
-        x = x + mm("thk,hkd->td", attention(q, k, v, block, quant),
-                   lw["wo"], quant)
-        h = rmsnorm(x, lw["ln2"], dims.norm_eps)
-        g = jax.nn.silu(mm("td,df->tf", h, lw["w_gate"], quant))
-        x = x + mm("tf,fd->td", g * mm("td,df->tf", h, lw["w_up"], quant),
-                   lw["w_down"], quant)
-    return rmsnorm(x, w["ln_f"], dims.norm_eps)
-
-
-@functools.partial(jax.jit, static_argnames=("dims", "block", "control"))
-def served_gaps(w, tokens, at, served, dims: Dims, block: int,
+@functools.partial(jax.jit,
+                   static_argnames=("hidden", "dims", "block", "control"))
+def served_gaps(w, tokens, at, served, hidden, dims, block: int,
                 control: bool = False):
     """For one sequence ``tokens`` [T] (prompt, then served tokens, then
     padding): at each position ``at[j]`` the reference's best logit
     minus its logit of ``served[j]``, the token the program served
-    next.  With ``control``, also the same gap of the token that the
-    fp8 forward puts first there.  Returns ``(gaps, control_gaps)``."""
+    next, ``hidden`` being the family's forward pass to the final norm.
+    With ``control``, also the same gap of the token that the fp8
+    forward puts first there.  Returns ``(gaps, control_gaps)``."""
     x = hidden(w, tokens, dims, block)[at]
     logits = mm("nd,dv->nv", x, w["unembed"])
     best = jnp.max(logits, -1)

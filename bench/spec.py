@@ -2,7 +2,8 @@
 
 A cell (``workloads/<cell>.json``) names its configuration
 (``configs/<config>.json``) and its traffic mix (``traffic/<mix>.json``);
-the mix names its generator (``traffic/<generator>.py``).  A metric's
+the configuration names its plain reference (``references/<name>.py``)
+and the mix its generator (``traffic/<generator>.py``).  A metric's
 reader is ``metrics/<base>.py``, where ``<base>`` is the metric's name up
 to its first ``.`` (``engine_step_ms.chat`` and ``engine_step_ms.code``
 share one reader).  Nothing here knows a cell, a mix or a metric by
@@ -27,16 +28,19 @@ class SpecError(ValueError):
 
 def _load_json(path: Path) -> dict:
     if not path.is_file():
-        raise SpecError(f"no such file: {path.relative_to(ROOT)}")
+        raise SpecError(f"no such file: {path}")
     with open(path) as f:
         return json.load(f)
 
 
 def load_config(name: str, bench: Path = BENCH) -> dict:
     cfg = _load_json(bench / "configs" / f"{name}.json")
-    for key in ("repro_config", "model", "source", "reduced", "deployment"):
+    for key in ("repro_config", "model", "reference", "source", "reduced",
+                "deployment"):
         if key not in cfg:
             raise SpecError(f"configs/{name}.json lacks {key!r}")
+    _file(bench / "references" / f"{cfg['reference']}.py",
+          "reference module")
     return cfg
 
 
@@ -47,22 +51,39 @@ def load_mix(name: str, bench: Path = BENCH) -> dict:
     return mix
 
 
-def _module(path: Path, what: str):
-    """Import the file ``path`` (a generator or a reader) by its path."""
+def _file(path: Path, what: str) -> None:
     if not path.is_file():
         raise SpecError(f"no {what} {path.name} in {path.parent}")
+
+
+def _module(path: Path, what: str):
+    """Import the file ``path`` (a generator, a reader or a reference
+    module) by its path."""
+    _file(path, what)
     name = f"bench:{path.resolve()}"
     if name not in sys.modules:
         mod_spec = importlib.util.spec_from_file_location(name, path)
         mod = importlib.util.module_from_spec(mod_spec)
-        mod_spec.loader.exec_module(mod)
+        # registered before it runs, as an import would be: a dataclass
+        # looks its module up while the class is made
         sys.modules[name] = mod
+        try:
+            mod_spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
     return sys.modules[name]
 
 
 def generator(name: str, bench: Path = BENCH):
     """The traffic generator ``traffic/<name>.py``."""
     return _module(bench / "traffic" / f"{name}.py", "traffic generator")
+
+
+def reference(name: str, bench: Path = BENCH):
+    """The plain reference module ``references/<name>.py``: one family's
+    sizes, weights, forward pass and the program's layout of them."""
+    return _module(bench / "references" / f"{name}.py", "reference module")
 
 
 def metric_reader(metric: str, bench: Path = BENCH):
@@ -81,11 +102,17 @@ class Cell:
     mix: dict
     end_to_end: List[dict]      # this cell's entries of BENCHMARK.json
     per_layer: List[dict]
+    bench: Path = BENCH         # the tree its files were found in
 
     @property
     def engine(self) -> dict:
         """The engine's sizes, which follow the traffic: the cell's."""
         return self.workload["engine"]
+
+    @property
+    def reference(self):
+        """The plain reference module its configuration names."""
+        return reference(self.config["reference"], self.bench)
 
 
 def _applies(metric: dict, cell: str, e2e_names: List[str]) -> bool:
@@ -114,7 +141,7 @@ def load_cell(name: str, benchmark: Optional[dict] = None,
     per = [m for m in benchmark.get("per_layer", [])
            if _applies(m, name, names)]
     return Cell(name, wl, load_config(wl["config"], bench),
-                load_mix(wl["traffic"], bench), e2e, per)
+                load_mix(wl["traffic"], bench), e2e, per, bench)
 
 
 def load_peaks(kind: str, bench: Path = BENCH) -> Dict[str, float]:

@@ -46,15 +46,13 @@ def main(argv=None) -> int:
 
     from bench import harness, program, spec
     from bench.metrics import itl_p95_ms, output_tok_s, ttft_p90_ms
-    from bench.reference import Dims, seed_key
+    from bench.reference import seed_key
     from bench.serve_loop import drive, warm_up
 
     cell = spec.load_cell(args.workload)
     harness.devices(int(cell.workload.get("chips", 1)), True)
-    dims = Dims.from_model(cell.config["model"])
-    model = program.build(cell.config)
-    params = program.make_params(model, seed_key(args.seed), dims)
-    gen = spec.generator(cell.mix["generator"])
+    dims, model, params = program.load(cell, seed_key(args.seed))
+    gen = spec.generator(cell.mix["generator"], cell.bench)
     mix = json.loads(json.dumps(cell.mix))
     if not args.backlog:
         mix["arrivals"]["backlog"] = 0

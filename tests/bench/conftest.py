@@ -22,8 +22,9 @@ def tiny_cell(rate_rps=15.0, **check):
     config at tiny widths, chat-like traffic."""
     from bench import spec
     config = {"name": "tiny", "repro_config": "internlm2-20b",
-              "model": dict(TINY_MODEL), "source": "test", "reduced": {},
-              "deployment": "test", "program": {"use_pallas": True}}
+              "reference": "dense_gqa", "model": dict(TINY_MODEL),
+              "source": "test", "reduced": {}, "deployment": "test",
+              "program": {"use_pallas": True}}
     mix = {"generator": "open_loop", "arrivals": {"process": "poisson"},
            "prompt_tokens": {"dist": "lognormal", "median": 20,
                              "sigma": 0.6, "min": 4, "max": 60},

@@ -15,7 +15,9 @@ import pytest
 from conftest import ROOT, tiny_cell
 
 from bench import harness, program
-from bench.reference import Dims, LAYER_LEAVES, seed_key, weight
+from bench.reference import seed_key
+from bench.references import dense_gqa
+from bench.references.dense_gqa import LAYER_LEAVES, model_dims, weight
 
 
 def _run(cell, seed, **kw):
@@ -85,10 +87,10 @@ def test_program_weights_are_the_reference_weights():
     from conftest import TINY_MODEL
     config = {"repro_config": "internlm2-20b", "model": TINY_MODEL,
               "program": {"use_pallas": True}}
-    dims = Dims.from_model(TINY_MODEL)
-    model = program.build(config)
+    dims = model_dims(TINY_MODEL)
+    model = program.build(config, dense_gqa)
     key = seed_key(2 ** 33 + 5)
-    p = program.make_params(model, key, dims)
+    p = program.make_params(model, key, dims, dense_gqa)
     for i in range(dims.n_layers):
         np.testing.assert_allclose(
             p["layers"]["attn"]["wq"][i], weight(key, "wq", i, dims),

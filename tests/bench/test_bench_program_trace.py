@@ -11,7 +11,7 @@ from conftest import TINY_MODEL, tiny_cell
 from jax.profiler import ProfileData
 
 from bench import harness, program_trace, spec, trace
-from bench.reference import Dims
+from bench.references.dense_gqa import model_dims
 from bench.serve_loop import Records, Tracked
 
 # device 0 (times in us): a loop [2, 9] holds the kernel [3, 5] and its
@@ -108,7 +108,7 @@ def _run(tr):
                     token_t=[2.0, 3.0, 11.0])]
     rec = Records(t0=0.0, t1=10.0, t_end=12.0, requests=reqs, steps=[],
                   lateness=[0.0, 0.0])
-    return harness.RunData(tiny_cell(), Dims.from_model(TINY_MODEL),
+    return harness.RunData(tiny_cell(), model_dims(TINY_MODEL),
                            spec.load_peaks("TPU v5 lite"), 1, 1.0, rec, tr)
 
 

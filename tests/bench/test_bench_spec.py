@@ -27,8 +27,10 @@ def test_dropped_in_files_are_found_by_name(tmp_path):
     shutil.copytree(spec.BENCH, bench,
                     ignore=shutil.ignore_patterns("__pycache__"))
     cfg = json.loads((bench / "configs" / "yi-34b.json").read_text())
-    cfg["name"] = "new-model"
+    cfg.update(name="new-model", reference="new_family")
     (bench / "configs" / "new-model.json").write_text(json.dumps(cfg))
+    (bench / "references" / "new_family.py").write_text(
+        "FAMILY = 'new'\n")
     mix = json.loads((bench / "traffic" / "chat.json").read_text())
     mix["generator"] = "fixed_gap"
     (bench / "traffic" / "steady.json").write_text(json.dumps(mix))
@@ -52,6 +54,8 @@ def test_dropped_in_files_are_found_by_name(tmp_path):
                        "moves": "ttft_p90_ms"}]}
     cell = spec.load_cell("new-model.steady", benchmark, bench)
     assert cell.config["name"] == "new-model"
+    assert cell.reference.FAMILY == "new"
+    assert spec.reference("new_family", bench) is cell.reference
     assert [m["name"] for m in cell.end_to_end] == ["output_tok_s"]
     assert [m["name"] for m in cell.per_layer] == ["new_metric.steady"]
     assert spec.generator(cell.mix["generator"], bench).generate(
@@ -59,13 +63,35 @@ def test_dropped_in_files_are_found_by_name(tmp_path):
     assert spec.metric_reader("new_metric.steady", bench).read(None) == 42.0
 
 
-def test_missing_files_are_errors(tmp_path):
+def _config_without_reference(bench):
+    cfg = json.loads((bench / "configs" / "yi-34b.json").read_text())
+    del cfg["reference"]
+    (bench / "configs" / "no-reference.json").write_text(json.dumps(cfg))
+    return spec.load_config("no-reference", bench)
+
+
+def _config_naming_a_missing_reference(bench):
+    cfg = json.loads((bench / "configs" / "yi-34b.json").read_text())
+    cfg["reference"] = "no_such_family"
+    (bench / "configs" / "lost.json").write_text(json.dumps(cfg))
+    return spec.load_config("lost", bench)
+
+
+@pytest.mark.parametrize("load", [
+    lambda bench: spec.load_config("no-such-model", bench),
+    lambda bench: spec.metric_reader("no_such_metric.chat", bench),
+    lambda bench: spec.load_cell("no.such.cell", {"workloads": []}, bench),
+    lambda bench: spec.reference("no_such_family", bench),
+    _config_without_reference,
+    _config_naming_a_missing_reference,
+], ids=["config", "metric_reader", "cell", "reference",
+        "config_without_reference", "config_naming_a_missing_reference"])
+def test_missing_files_are_errors(tmp_path, load):
+    bench = tmp_path / "bench"
+    shutil.copytree(spec.BENCH, bench,
+                    ignore=shutil.ignore_patterns("__pycache__"))
     with pytest.raises(spec.SpecError):
-        spec.load_config("no-such-model")
-    with pytest.raises(spec.SpecError):
-        spec.metric_reader("no_such_metric.chat")
-    with pytest.raises(spec.SpecError):
-        spec.load_cell("no.such.cell", {"workloads": []})
+        load(bench)
 
 
 def test_cell_files_must_agree_with_the_benchmark():

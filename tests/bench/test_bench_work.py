@@ -7,7 +7,8 @@ import pytest
 from bench import spec
 from bench.metrics import paged_attention_roofline as pa
 from bench.metrics import step_mfu_pct as mfu
-from bench.reference import Dims
+from bench.references import dense_gqa
+from bench.references.dense_gqa import Dims, matmul_params, model_dims
 
 INTERNLM2 = Dims(n_layers=3, d_model=6144, n_heads=48, n_kv_heads=8,
                  head_dim=128, d_ff=16384, vocab=92544, rope_theta=1e6,
@@ -56,10 +57,10 @@ def test_mfu_matmul_params_match_count_params():
 
     from bench.program import model_cfg
     config = {"repro_config": "internlm2-20b", "model": TINY_MODEL}
-    dims = Dims.from_model(TINY_MODEL)
-    total = count_params(model_cfg(config))
+    dims = model_dims(TINY_MODEL)
+    total = count_params(model_cfg(config, dense_gqa))
     d, L = dims.d_model, dims.n_layers
-    assert mfu.matmul_params(dims) + d * dims.vocab == \
+    assert matmul_params(dims) + d * dims.vocab == \
         total - dims.vocab * d - (2 * L + 1) * d
 
 
