@@ -22,6 +22,24 @@ class MoECfg:
     capacity_factor: float = 1.0
     router_aux_coef: float = 1e-2
     router_z_coef: float = 1e-3
+    # the top-k gates are divided by their sum (Mixtral, Qwen MoE);
+    # OLMoE keeps the softmax over all experts as it is
+    norm_topk_prob: bool = True
+    # expert parallelism: the experts are split into ``expert_shards``
+    # equal contiguous groups and this chip holds group ``expert_shard``.
+    # The router still scores all ``n_experts``; the layer computes the
+    # part of the result its own experts give
+    expert_shards: int = 1
+    expert_shard: int = 0
+
+    @property
+    def n_held(self) -> int:
+        """Experts this chip holds."""
+        return self.n_experts // self.expert_shards
+
+    @property
+    def first_held(self) -> int:
+        return self.expert_shard * self.n_held
 
 
 @dataclass(frozen=True)
@@ -75,6 +93,9 @@ class ModelCfg:
     attn_softcap: Optional[float] = None
     logit_softcap: Optional[float] = None
     qk_norm: bool = False
+    # RMSNorm of q and k over each head's width (Gemma 3: "head") or over
+    # the whole projection before the split into heads (OLMoE: "full")
+    qk_norm_width: str = "head"
     mla: Optional[MLACfg] = None
     # --- mixture of experts --------------------------------------------------
     moe: Optional[MoECfg] = None

@@ -136,6 +136,24 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
                    ctx.kernel_mesh())
 
 
+@jax.jit
+def _gmm_jit(x, w, group_sizes):
+    return jax.lax.ragged_dot(x, w, group_sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def grouped_matmul(x, w, group_sizes):
+    """Grouped matmul of the expert layer: ``x`` [M, K] holds rows sorted
+    by group, ``w`` [G, K, N] one matrix per group, ``group_sizes`` [G]
+    int32 the rows of each group; row ``i`` of group ``g`` gives
+    ``x[i] @ w[g]`` (float32).  Rows past ``sum(group_sizes)`` belong to
+    no group, and their values are unspecified.  ``jax.lax.ragged_dot``:
+    on a TPU, XLA lowers it to its grouped-matmul kernels, whose device
+    ops are named ``%ragged-dot-*`` (the compiler's names, not this
+    wrapper's)."""
+    return _gmm_jit(x, w, group_sizes)
+
+
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def _ssm_jit(x, dt, B, C, A, block_d, interpret):
     return _ssm.ssm_scan(x, dt, B, C, A, block_d=block_d,

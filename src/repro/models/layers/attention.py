@@ -41,8 +41,11 @@ def init_attention(key, cfg):
                                  -(h * hd) ** -0.5, (h * hd) ** -0.5),
     }
     if cfg.qk_norm:
-        p["q_norm"] = {"scale": jnp.zeros((hd,), jnp.float32)}
-        p["k_norm"] = {"scale": jnp.zeros((hd,), jnp.float32)}
+        full = cfg.qk_norm_width == "full"
+        p["q_norm"] = {"scale": jnp.zeros((h * hd if full else hd,),
+                                          jnp.float32)}
+        p["k_norm"] = {"scale": jnp.zeros((kh * hd if full else hd,),
+                                          jnp.float32)}
     return p
 
 
@@ -152,11 +155,21 @@ def attend(q, k, v, qpos, kpos, *, scale, causal=True, window=None, n_sink=0,
     return out[:, :Sq]
 
 
+def _qk_norm(p, x, cfg):
+    """RMSNorm of projected q or k [B,S,heads,hd]: over each head's width
+    (``qk_norm_width`` "head", Gemma 3), or over the whole projection
+    before its split into heads ("full", OLMoE)."""
+    if cfg.qk_norm_width == "head":
+        return rmsnorm(p, x, cfg.norm_eps)
+    return rmsnorm(p, x.reshape(*x.shape[:2], -1), cfg.norm_eps
+                   ).reshape(x.shape)
+
+
 def _project_q(p, x, cfg):
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
     q = ctx.constrain(q, "batch", None, "model", None)
     if cfg.qk_norm:
-        q = rmsnorm(p["q_norm"], q, cfg.norm_eps)
+        q = _qk_norm(p["q_norm"], q, cfg)
     return q
 
 
@@ -167,7 +180,7 @@ def _project_kv(p, xk, cfg):
     k = ctx.constrain(k, "batch", None, "model", None)
     v = ctx.constrain(v, "batch", None, "model", None)
     if cfg.qk_norm:
-        k = rmsnorm(p["k_norm"], k, cfg.norm_eps)
+        k = _qk_norm(p["k_norm"], k, cfg)
     return k, v
 
 

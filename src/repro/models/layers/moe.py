@@ -14,6 +14,17 @@ Sharding strategy (EP over the 'model' mesh axis):
 
 Capacity C = ceil(S * top_k / E * capacity_factor); overflow tokens are
 dropped (their combine weight is 0), underflow slots read a zero row.
+That capacity path is the training step's (``moe_ffn``, and
+``moe_ffn_sharded`` under ``moe_impl="shard"``).
+
+Serving (every mode but ``train``) runs ``expert_layer``: dropless over
+every token of the launch, and told which experts it holds
+(``MoECfg.expert_shards`` / ``expert_shard``).  It routes over all the
+experts, keeps the (token, expert) pairs of its own, sorts them by
+expert into a buffer of static size ``T * top_k`` with dynamic group
+sizes, runs the grouped matmul (``kernels.ops.grouped_matmul``) over the
+held experts only, and combines with the gates: one chip's share of an
+expert-parallel layer, with no stand-in for the other chips' part.
 """
 from __future__ import annotations
 
@@ -38,9 +49,9 @@ def init_moe(key, cfg):
     u = lambda kk, shape, l: jax.random.uniform(kk, shape, jnp.float32, -l, l)
     p = {
         "router": u(k[0], (d, m.n_experts), lim_d),
-        "w_gate": u(k[1], (m.n_experts, d, f), lim_d),
-        "w_up": u(k[2], (m.n_experts, d, f), lim_d),
-        "w_down": u(k[3], (m.n_experts, f, d), lim_f),
+        "w_gate": u(k[1], (m.n_held, d, f), lim_d),
+        "w_up": u(k[2], (m.n_held, d, f), lim_d),
+        "w_down": u(k[3], (m.n_held, f, d), lim_f),
     }
     if m.n_shared:
         from repro.models.layers.basic import init_mlp
@@ -61,16 +72,25 @@ def moe_specs(cfg):
     return s
 
 
-def _route(logits, top_k, cap):
+def top_k_gates(logits, top_k, normalize=True):
+    """logits [..., E] f32 -> (gates [..., k], eid [..., k]): the softmax
+    over all experts, its ``top_k`` largest, and with ``normalize``
+    (``MoECfg.norm_topk_prob``) those divided by their sum."""
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, eid = jax.lax.top_k(probs, top_k)
+    if normalize:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    return gates, eid
+
+
+def _route(logits, top_k, cap, normalize=True):
     """logits [S,E] f32 -> (gates [S,k], eid [S,k], slot_pos [S,k], keep [S,k]).
 
     slot_pos is each (token, k)-slot's position within its expert's capacity
     buffer, assigned in token order (earlier tokens win on overflow).
     """
     S, E = logits.shape
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, eid = jax.lax.top_k(probs, top_k)                    # [S,k]
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    gates, eid = top_k_gates(logits, top_k, normalize)          # [S,k]
     oh = jax.nn.one_hot(eid.reshape(S * top_k), E, dtype=jnp.int32)
     pos = jnp.cumsum(oh, axis=0) - 1                            # [S*k,E]
     slot_pos = jnp.take_along_axis(
@@ -82,6 +102,8 @@ def _route(logits, top_k, cap):
 def moe_ffn(p, x, cfg, batch_axes=("data",)):
     """x [B,S,D] -> (out [B,S,D], aux_losses dict)."""
     m = cfg.moe
+    if m.n_held != m.n_experts:
+        raise NotImplementedError("the capacity path holds every expert")
     cdt = x.dtype
     B, S, D = x.shape
     E, K = m.n_experts, m.top_k
@@ -91,7 +113,7 @@ def moe_ffn(p, x, cfg, batch_axes=("data",)):
                         ).astype(jnp.float32)
 
     def route_row(lg):  # [S,E]
-        gates, eid, slot_pos, keep = _route(lg, K, C)
+        gates, eid, slot_pos, keep = _route(lg, K, C, m.norm_topk_prob)
         tok = jnp.arange(S, dtype=jnp.int32)[:, None] * jnp.ones((1, K), jnp.int32)
         # dispatch index [E,C]: source token for each capacity slot (S = pad)
         e_flat = jnp.where(keep, eid, E).reshape(-1)            # drop -> OOB
@@ -142,6 +164,61 @@ def moe_ffn(p, x, cfg, batch_axes=("data",)):
         "moe_router_z": jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2),
     }
     return out, aux
+
+
+# ---------------------------------------------------------------------------
+# the serving layer: dropless, over the experts this chip holds
+
+# what ``expert_layer`` counts per launch; ``EXPERT_MAX`` merges over
+# layers by maximum, the others by sum
+EXPERT_PAIRS, EXPERT_MAX = "expert_pairs", "expert_max_load"
+
+
+def expert_layer(p, x, cfg, valid=None):
+    """x [B,S,D] -> (out [B,S,D], counts): this chip's part of the MoE
+    layer for every token of the launch, none dropped.
+
+    ``valid`` [B,S] bool marks real tokens (a masked decode row or a
+    chunk's left padding is not); others route nowhere.  ``counts``:
+    ``EXPERT_PAIRS``, the (token, held expert) pairs computed, and
+    ``EXPERT_MAX``, the most pairs one held expert received (int32)."""
+    from repro.kernels import ops as kops
+    m = cfg.moe
+    cdt = x.dtype
+    B, S, D = x.shape
+    T, K, Eh = B * S, m.top_k, m.n_held
+    xt = x.reshape(T, D)
+    # the router in float32: a top-k pick must not turn on bf16 rounding
+    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    gates, eid = top_k_gates(logits, K, m.norm_topk_prob)      # [T,K]
+    local = eid - m.first_held
+    held = (local >= 0) & (local < Eh)
+    if valid is not None:
+        held &= valid.reshape(T, 1)
+    # pairs sorted by held expert into a [T*K] buffer; the rest go last,
+    # past every group, where the grouped matmul writes nothing used
+    key = jnp.where(held, local, Eh).reshape(T * K)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.zeros((Eh + 1,), jnp.int32).at[key].add(1)[:Eh]
+    xs = xt[order // K]                                         # [T*K, D]
+    g = kops.grouped_matmul(xs, p["w_gate"].astype(cdt), sizes)
+    u = kops.grouped_matmul(xs, p["w_up"].astype(cdt), sizes)
+    h = (act_fn(cfg.act)(g) * u).astype(cdt)
+    y = kops.grouped_matmul(h, p["w_down"].astype(cdt), sizes)
+    # back to token order (a gather by the inverse permutation), then the
+    # gate-weighted sum over each token's held picks
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(T * K, dtype=order.dtype))
+    yk = jnp.where(held[..., None], y[inv].reshape(T, K, D), 0.0)
+    out = jnp.einsum("tkd,tk->td", yk, jnp.where(held, gates, 0.0))
+    out = out.astype(cdt).reshape(B, S, D)
+    if m.n_shared:
+        # every chip runs the shared expert on its own tokens alike
+        from repro.models.layers.basic import mlp
+        out = out + mlp(p["shared"], x, cfg.act)
+    counts = {EXPERT_PAIRS: sizes.sum(), EXPERT_MAX: sizes.max()}
+    return out, counts
 
 
 # ---------------------------------------------------------------------------
@@ -200,7 +277,8 @@ def moe_ffn_sharded(p, x, cfg):
             wg, wu, wd = cast(wg), cast(wu), cast(wd)
 
         def route_row(lgr):                              # [S,E]
-            gates, eid, slot_pos, keep = _route(lgr, K, C)
+            gates, eid, slot_pos, keep = _route(lgr, K, C,
+                                                m.norm_topk_prob)
             local = (eid >= shard * E_loc) & (eid < (shard + 1) * E_loc)
             keep = keep & local
             e_loc = eid - shard * E_loc

@@ -208,13 +208,36 @@ def _split_cache(cache, kind):
 # one decoder layer
 
 
+def _aux_zeros(cfg, mode):
+    """A layer's aux values before it runs: the router losses, and for
+    a serving MoE the expert layer's counts (``expert_counts``)."""
+    aux = {"moe_load_balance": jnp.zeros((), jnp.float32),
+           "moe_router_z": jnp.zeros((), jnp.float32)}
+    if cfg.moe is not None and mode != "train":
+        aux.update({moe_mod.EXPERT_PAIRS: jnp.zeros((), jnp.int32),
+                    moe_mod.EXPERT_MAX: jnp.zeros((), jnp.int32)})
+    return aux
+
+
+def _merge_aux(a, b):
+    return {k: (jnp.maximum if k == moe_mod.EXPERT_MAX else jnp.add)(
+        a[k], b[k]) for k in a}
+
+
+def expert_counts(aux) -> dict:
+    """The launch's expert-layer counts out of ``lm_apply``'s aux, summed
+    over layers (the most pairs one expert got: the largest in any
+    layer); empty for a model without experts."""
+    return {k: aux[k] for k in (moe_mod.EXPERT_PAIRS, moe_mod.EXPERT_MAX)
+            if k in aux}
+
+
 def _layer(x, lp, *, cfg, positions, is_global, cache_layer, write_pos, mode,
            block_tables=None):
     """Returns (x, new_cache_layer, aux)."""
     cdt = x.dtype
     x = ctx.constrain(x, "batch", None, None)
-    aux = {"moe_load_balance": jnp.zeros((), jnp.float32),
-           "moe_router_z": jnp.zeros((), jnp.float32)}
+    aux = _aux_zeros(cfg, mode)
     norm = _norm(cfg)
 
     if cfg.family == "ssm":
@@ -277,7 +300,14 @@ def _layer(x, lp, *, cfg, positions, is_global, cache_layer, write_pos, mode,
     x = x + a_out
 
     h_in = norm(lp["ln2"], x, cfg.norm_eps)
-    if "router" in lp["ffn"]:
+    if "router" in lp["ffn"] and mode != "train":
+        # serving: every token of the launch (all rows of a decode, all
+        # tokens of a chunk) in one dropless call over the held experts
+        with jax.named_scope("moe"):
+            f_out, counts = moe_mod.expert_layer(lp["ffn"], h_in, cfg,
+                                                 valid=positions >= 0)
+        aux.update(counts)
+    elif "router" in lp["ffn"]:
         moe_fn = (moe_mod.moe_ffn_sharded if cfg.moe_impl == "shard"
                   else moe_mod.moe_ffn)
         f_out, moe_aux = moe_fn(lp["ffn"], h_in, cfg)
@@ -374,8 +404,7 @@ def lm_apply(params, cfg, *, tokens, mode, prefix_embeds=None, cache=None,
 
     n_pre = _n_pre_layers(cfg)
     glob = jnp.asarray(cfg.global_layer_mask(), bool)
-    aux_tot = {"moe_load_balance": jnp.zeros((), jnp.float32),
-               "moe_router_z": jnp.zeros((), jnp.float32)}
+    aux_tot = _aux_zeros(cfg, mode)
     max_len = max_len or St
     pre_caches = []
 
@@ -386,7 +415,7 @@ def lm_apply(params, cfg, *, tokens, mode, prefix_embeds=None, cache=None,
                              is_global=glob[i], cache_layer=cl,
                              write_pos=write_pos, mode=mode,
                              block_tables=block_tables)
-        aux_tot = jax.tree.map(jnp.add, aux_tot, aux)
+        aux_tot = _merge_aux(aux_tot, aux)
         if mode != "train":
             pre_caches.append(_prefill_pad_cache(ncl, max_len)
                               if mode == "prefill" else ncl)
@@ -408,7 +437,7 @@ def lm_apply(params, cfg, *, tokens, mode, prefix_embeds=None, cache=None,
                              is_global=g, cache_layer=cl,
                              write_pos=write_pos, mode=mode,
                              block_tables=block_tables)
-        aux_acc = jax.tree.map(jnp.add, aux_acc, aux)
+        aux_acc = _merge_aux(aux_acc, aux)
         if mode == "train":
             ys = 0.0
         elif mode == "prefill":

@@ -8,7 +8,8 @@
   loss(params, batch)               -> (scalar loss, aux dict)
   prefill(params, batch, max_len)   -> (last_logits, cache)
   decode(params, cache, tokens,pos) -> (logits, new cache)
-  decode_step(params, cache, tokens, pos) -> (next_tokens, new cache)
+  decode_step(params, cache, tokens, pos) -> (next_tokens, new cache[,
+                                               expert counts (moe)])
   init_cache(batch, max_len)        -> decode cache
   cache_specs(batch_axes, seq_axis) -> PartitionSpec pytree for the cache
   input_specs(cell)                 -> ShapeDtypeStructs for a shape cell
@@ -78,7 +79,8 @@ class Model:
     # last-position logit head) runs INSIDE the step, so a jitted/AOT
     # caller moves only [B] int32 tokens across the host boundary
     # instead of [B, vocab] logits.  Same signature as ``decode`` but
-    # returns (next_tokens [B] int32, new_cache).
+    # returns (next_tokens [B] int32, new_cache); an MoE model's adds a
+    # third output, the launch's expert counts (int32 scalars by name)
     decode_step: Optional[Callable] = None
 
 
@@ -175,10 +177,20 @@ def _build_lm(cfg: ModelCfg) -> Model:
         return {"tokens": bspec, "pos": P(batch_axes),
                 "cache": cache_specs(batch_axes, seq_axis)}
 
+    decode_step = fused_decode_step(decode)
+    if cfg.moe is not None:
+        def decode_step(params, cache, tokens, pos, block_tables=None):
+            """The fused step, and the launch's expert-layer counts as a
+            third output (``transformer.expert_counts``)."""
+            logits, aux, cache = lm_mod.lm_apply(
+                params, cfg, tokens=tokens, mode="decode", cache=cache,
+                write_pos=pos, block_tables=block_tables)
+            nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+            return nxt, cache, lm_mod.expert_counts(aux)
+
     return Model(cfg, init, param_specs, loss, prefill, decode, init_cache,
                  cache_specs, input_specs, input_shardings,
-                 init_paged_cache=init_paged_cache,
-                 decode_step=fused_decode_step(decode))
+                 init_paged_cache=init_paged_cache, decode_step=decode_step)
 
 
 # ---------------------------------------------------------------------------

@@ -172,8 +172,8 @@ class FaultyReplica:
         if eng._pending is None:
             return False
         import jax
-        io, snap = eng._pending
+        io, *rest = eng._pending
         arr = np.array(jax.device_get(io))
         arr[1, :] = -1
-        eng._pending = (arr, snap)
+        eng._pending = (arr, *rest)
         return True

@@ -84,6 +84,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.core.costmodel.model import CostModel, Prediction
+from repro.models.layers.moe import EXPERT_MAX, EXPERT_PAIRS
 from repro.models.zoo import Model, fused_decode_step
 from repro.serve.paging import (BlockAllocator, blocks_for_tokens,
                                 remap_table)
@@ -131,6 +132,11 @@ class EngineStats:
     peak_blocks_in_use: int = 0
     admission_order: List[int] = dataclasses.field(default_factory=list)
     integrity_failures: int = 0     # corrupted fused-step drains dropped
+    # an MoE model's expert layer (fused paged path; 0 for a dense model):
+    # (token, held expert) pairs computed, summed over launches and
+    # layers, and the most pairs one held expert got in any launch
+    expert_pairs: int = 0
+    expert_max_load: int = 0
 
 
 def _analytic_prefill_prediction(cost_model: CostModel, cfg,
@@ -155,6 +161,12 @@ def _decode_step_fn(model):
     if getattr(model, "decode_step", None) is not None:
         return model.decode_step
     return fused_decode_step(model.decode)
+
+
+def _split_step(out):
+    """A decode step's ``(next_tokens, cache, counts)``: a dense model's
+    step returns no counts (``Model.decode_step``)."""
+    return (*out, {}) if len(out) == 2 else out
 
 
 def _echo_ok(arr: np.ndarray) -> bool:
@@ -200,7 +212,7 @@ class _TunedDispatch:
         with TraceAnnotation(spans.SYNC):
             host = jax.device_get(x)
         self._sync_s += self._clock.perf_counter() - t
-        return np.asarray(host)
+        return jax.tree.map(np.asarray, host)
 
     def _book_first_token(self, req: Request, tok: int) -> None:
         """Append a request's first token; a replay after eviction keeps
@@ -278,7 +290,8 @@ class ServingEngine(_TunedDispatch):
             self._pos = jnp.zeros((max_batch,), jnp.int32)
 
             def fused_step(params, cache, toks, pos):
-                nxt, cache = step_fn(params, cache, toks[:, None], pos)
+                nxt, cache, _ = _split_step(
+                    step_fn(params, cache, toks[:, None], pos))
                 io = jnp.stack([toks, nxt])      # input echo + outputs
                 return io, nxt, pos + 1, cache
 
@@ -590,27 +603,31 @@ class _Row:
 
 def paged_decode_fn(step_fn, mesh=None):
     """The paged engine's fused decode step before jit: one greedy token
-    per row, the ``[2, B]`` echo of inputs and outputs, and masked rows
-    (``pos < 0``) keeping their resident token.  With a replica ``mesh``
+    per row, the ``[2, B]`` echo of inputs and outputs, masked rows
+    (``pos < 0``) keeping their resident token, and the step's expert
+    counts (an empty dict for a dense model).  With a replica ``mesh``
     the paged-attention kernel, which GSPMD cannot partition, runs per
     shard (``sharding.ctx.use_kernel_mesh``)."""
     def fused_decode(params, cache, toks, pos, bt):
         with ctx.use_kernel_mesh(mesh):
-            nxt, cache = step_fn(params, cache, toks[:, None], pos, bt)
+            nxt, cache, counts = _split_step(
+                step_fn(params, cache, toks[:, None], pos, bt))
         io = jnp.stack([toks, nxt])
-        return io, jnp.where(pos >= 0, nxt, toks), cache
+        return io, jnp.where(pos >= 0, nxt, toks), cache, counts
     return fused_decode
 
 
 def paged_chunk_fn(step_fn, mesh=None):
     """The paged engine's fused prefill-chunk step before jit: only a
     prompt's FINAL chunk writes its first token into the device token
-    array; intermediate chunks leave the row's slot untouched."""
+    array; intermediate chunks leave the row's slot untouched.  Its
+    expert counts come out beside them."""
     def fused_chunk(params, cache, toks, start, bt, toks_dev, idx, final):
         with ctx.use_kernel_mesh(mesh):
-            nxt, cache = step_fn(params, cache, toks, start, bt)
+            nxt, cache, counts = _split_step(
+                step_fn(params, cache, toks, start, bt))
         tok0 = jnp.where(final, nxt[0], toks_dev[idx])
-        return cache, toks_dev.at[idx].set(tok0)
+        return cache, toks_dev.at[idx].set(tok0), counts
     return fused_chunk
 
 
@@ -731,6 +748,10 @@ class PagedServingEngine(_TunedDispatch):
         self._pred_cache: Dict = {}
         self._decode_text: Optional[str] = None
         self._pending = None
+        # expert counts of the launches since the last decode dispatch,
+        # on the device until the decode's echo is synced
+        self._launch_counts: List[dict] = []
+        self._drained_counts: Dict[str, int] = {}
         step_fn = _decode_step_fn(model)
         if fused:
             self._toks = self._dev(np.zeros(max_batch, np.int32), "batch")
@@ -754,13 +775,14 @@ class PagedServingEngine(_TunedDispatch):
                     fused_decode, donate_argnums=(1,),
                     in_shardings=(self._param_sh, pool_sh, sh["batch"],
                                   sh["batch"], sh["repl"]),
-                    out_shardings=(sh["io"], sh["batch"], pool_sh))
+                    out_shardings=(sh["io"], sh["batch"], pool_sh,
+                                   sh["repl"]))
                 self._chunk = jax.jit(
                     fused_chunk, donate_argnums=(1, 5),
                     in_shardings=(self._param_sh, pool_sh, sh["repl"],
                                   sh["repl"], sh["repl"], sh["batch"],
                                   sh["repl"], sh["repl"]),
-                    out_shardings=(pool_sh, sh["batch"]))
+                    out_shardings=(pool_sh, sh["batch"], sh["repl"]))
         else:
             self._decode = jax.jit(model.decode)     # batch decode [B, 1]
             self._chunk = jax.jit(model.decode)      # chunk prefill [1, C]
@@ -1017,8 +1039,10 @@ class PagedServingEngine(_TunedDispatch):
         with TraceAnnotation(spans.LAUNCH):
             bt = bt[idx:idx + 1]      # a program of its own on the device
             if self.fused:
-                self.cache, self._toks = self._chunk(
+                self.cache, self._toks, counts = self._chunk(
                     self.params, self.cache, *head, bt, self._toks, *tail)
+                if counts:
+                    self._launch_counts.append(counts)
             else:
                 logits, self.cache = self._chunk(
                     self.params, self.cache, *head, bt)
@@ -1041,7 +1065,7 @@ class PagedServingEngine(_TunedDispatch):
         with TraceAnnotation(spans.STEP) as span:
             n, rows = self._iterate()
             if span.is_enabled():
-                span.set_metadata(rows=rows)
+                span.set_metadata(rows=rows, **self._drained_counts)
         return n
 
     def _iterate(self) -> "tuple[int, int]":
@@ -1052,6 +1076,7 @@ class PagedServingEngine(_TunedDispatch):
         row count and the rows the decode stepped."""
         t0, self._sync_s = self._clock.perf_counter(), 0.0
         prev, self._pending = self._pending, None
+        self._drained_counts = {}
         unfinished = sorted(
             ((i, self.rows[i].req.rid, self.rows[i].req)
              for i in self._placed() if not self.rows[i].ready),
@@ -1172,13 +1197,18 @@ class PagedServingEngine(_TunedDispatch):
             with TraceAnnotation(spans.UPLOAD):
                 pos_d, bt = self._dev(pos, "batch"), self._bt_device()
             with TraceAnnotation(spans.LAUNCH):
-                io, self._toks, self.cache = self._decode(
+                io, self._toks, self.cache, counts = self._decode(
                     self.params, self.cache, self._toks, pos_d, bt)
+            if counts:
+                self._launch_counts.append(counts)
             # the snapshot carries each row's post-step position: that is
             # the value retire checks compare against at drain time
-            # (row.pos itself may advance again before the drain)
+            # (row.pos itself may advance again before the drain); the
+            # launches' expert counts ride along to the same sync
             self._pending = (io, [(i, row, row.pos + 1)
-                                  for i, row in stepping])
+                                  for i, row in stepping],
+                             self._launch_counts)
+            self._launch_counts = []
             for i, row in stepping:
                 row.pos += 1
                 row.dispatched += 1
@@ -1210,8 +1240,9 @@ class PagedServingEngine(_TunedDispatch):
         by identity, so replays and shadow steps never double-count."""
         if pending is None:
             return
-        io, snap = pending
-        arr = self._sync(io)
+        io, snap, launches = pending
+        arr, launches = self._sync((io, launches))
+        self._book_expert_counts(launches)
         if not _echo_ok(arr):
             self.stats.integrity_failures += 1   # see the slot _drain
             return
@@ -1229,6 +1260,17 @@ class PagedServingEngine(_TunedDispatch):
             out_of_cache = pos_after >= self.max_len - 1
             if hit_eos or out_of_budget or out_of_cache:
                 self._retire(i)
+
+    def _book_expert_counts(self, launches: List[dict]) -> None:
+        """Add the synced launches' expert counts to ``stats``; the step
+        span (``serve.step``) carries their sum and maximum."""
+        if not launches:
+            return
+        pairs = sum(int(c[EXPERT_PAIRS]) for c in launches)
+        most = max(int(c[EXPERT_MAX]) for c in launches)
+        self.stats.expert_pairs += pairs
+        self.stats.expert_max_load = max(self.stats.expert_max_load, most)
+        self._drained_counts = {EXPERT_PAIRS: pairs, EXPERT_MAX: most}
 
     @spans.traced(spans.RETIRE)
     def _retire(self, idx: int) -> None:

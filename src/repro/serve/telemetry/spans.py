@@ -21,7 +21,11 @@ from jax.profiler import annotate_function
 
 # one engine iteration (``_step``): the interval ``StepRecord.measured_s``
 # times.  At its end it carries the stat ``rows``, the rows the decode
-# dispatch stepped (rows mid-prefill ride along masked and do not count)
+# dispatch stepped (rows mid-prefill ride along masked and do not count),
+# and for an MoE model ``expert_pairs`` and ``expert_max_load``: the
+# expert counts of the launches its sync brought back (the previous
+# iteration's chunks and decode, counted on the device and carried out
+# beside the tokens, so they cost no sync of their own)
 STEP = "serve.step"
 # the scheduler's plan for the iteration, with its cost-model pricing
 PLAN = "serve.plan"

@@ -25,7 +25,7 @@ from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.models.zoo import build_model
-from repro.serve.engine import paged_decode_fn
+from repro.serve.engine import paged_chunk_fn, paged_decode_fn
 from repro.sharding.plans import (named_tree, paged_decode_shardings,
                                   sanitize_specs, strip_axis)
 
@@ -84,10 +84,10 @@ def _total_bytes(compiled):
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
-def _cell_engine():
-    """The chat cell's engine: its decode batch, pool and table width."""
+def _cell_engine(cell="internlm2-20b.chat"):
+    """A cell's engine: its decode batch, pool and table width."""
     path = (Path(__file__).resolve().parents[1] / "bench" / "workloads"
-            / "internlm2-20b.chat.json")
+            / f"{cell}.json")
     return json.loads(path.read_text())["engine"]
 
 
@@ -170,9 +170,47 @@ def test_sharded_paged_decode_step_compiles_for_v5e_2x2(topo, on_tpu):
                    donate_argnums=(1,),
                    in_shardings=(psh, pool_sh, sh["batch"], sh["batch"],
                                  sh["repl"]),
-                   out_shardings=(sh["io"], sh["batch"], pool_sh))
+                   out_shardings=(sh["io"], sh["batch"], pool_sh,
+                                  sh["repl"]))
     compiled = step.lower(params, pool, i32((B,), sh["batch"]),
                           i32((B,), sh["batch"]),
                           i32((B, NB), sh["repl"])).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert _total_bytes(compiled) <= V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_olmoe_steps_compile_for_v5e_and_fit(one_chip, on_tpu, program):
+    """OLMoE-1B-7B as the ``olmoe-1b-7b.chat4k`` cell runs it: 4 layers,
+    16 of 64 experts held, the cell's pool, its 64 decode rows or one
+    256-token chunk.  The expert layer's grouped matmul lowers to the
+    TPU's grouped-matmul kernels, and each program fits one chip."""
+    cell = _cell_engine("olmoe-1b-7b.chat4k")
+    base = get_config("olmoe-1b-7b")
+    cfg = dataclasses.replace(
+        base, n_layers=4, use_pallas=True,
+        moe=dataclasses.replace(base.moe, expert_shards=4, expert_shard=0))
+    model = build_model(cfg)
+    B, C, bs = cell["max_batch"], cell["chunk_size"], cell["block_size"]
+    NB = -(-cell["max_len"] // bs)
+    params = _placed(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                     one_chip)
+    assert params["layers"]["ffn"]["w_gate"].shape == (4, 16, 2048, 1024)
+    pool = _placed(jax.eval_shape(
+        lambda: model.init_paged_cache(cell["n_blocks"], bs)), one_chip)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+    if program == "decode":
+        lowered = jax.jit(paged_decode_fn(model.decode_step),
+                          donate_argnums=(1,)).lower(
+            params, pool, i32(B), i32(B), i32(B, NB))
+    else:
+        lowered = jax.jit(paged_chunk_fn(model.decode_step),
+                          donate_argnums=(1, 5)).lower(
+            params, pool, i32(1, C), i32(1), i32(1, NB), i32(B), i32(),
+            jax.ShapeDtypeStruct((), jnp.bool_, sharding=one_chip))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "%ragged-dot" in text
+    if program == "decode":
+        assert "%_pa_jit" in text
     assert _total_bytes(compiled) <= V5E_HBM_BYTES
