@@ -83,27 +83,31 @@ def flash_attention(q, k, v, causal=True, window=None, softcap=None,
 @functools.partial(jax.jit, static_argnames=("scale", "window", "softcap",
                                              "interpret", "hbm",
                                              "num_splits", "mesh"))
-def _pa_jit(q, k_pages, v_pages, block_tables, context_lens, scale, window,
-            softcap, interpret, hbm, num_splits, mesh):
+def _pa_jit(q, k_pages, v_pages, block_tables, context_lens, layer, scale,
+            window, softcap, interpret, hbm, num_splits, mesh):
     if mesh is not None:
         # a sharded replica's step: GSPMD cannot partition the kernel, so
         # it runs per shard on the local heads and batch rows
         return _pa.paged_attention_sharded(
-            q, k_pages, v_pages, block_tables, context_lens, mesh,
+            q, k_pages, v_pages, block_tables, context_lens, mesh, layer,
             scale=scale, window=window, softcap=softcap,
             num_splits=num_splits, hbm=hbm, interpret=interpret)
     fn = _pa.paged_attention_hbm if hbm else _pa.paged_attention
-    return fn(q, k_pages, v_pages, block_tables, context_lens, scale=scale,
-              window=window, softcap=softcap, num_splits=num_splits,
-              interpret=interpret)
+    return fn(q, k_pages, v_pages, block_tables, context_lens, layer,
+              scale=scale, window=window, softcap=softcap,
+              num_splits=num_splits, interpret=interpret)
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
                     scale=None, window=None, softcap=None, num_splits=None,
-                    config=None, tuned=False, interpret=None, hbm=None):
-    """Paged decode attention.  Two tunable axes resolve differently:
+                    config=None, tuned=False, interpret=None, hbm=None,
+                    layer=0):
+    """Paged decode attention over one layer's pool ``[P, bs, KH, D]``,
+    or over the stacked ``[L, P, bs, KH, D]`` at ``layer`` (an int32
+    scalar, may be traced): the kernel reads that layer's pages where
+    they lie.  Two tunable axes resolve differently:
     ``block_size`` is a CACHE-LAYOUT parameter, fixed here by
-    ``k_pages.shape[1]`` — the paged serving engine consults the tuning
+    the pool's page size — the paged serving engine consults the tuning
     cache (``Autotuner.config_for('paged_attention', ...)``) when it lays
     out the block pool, not at dispatch time.  ``num_splits`` (the
     split-KV flash-decoding grid axis) is a pure LAUNCH parameter and
@@ -125,15 +129,15 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
         hbm = jax.default_backend() == "tpu"
     NB = block_tables.shape[1]
     shapes = {"batch": q.shape[0], "heads": q.shape[1],
-              "kv_heads": k_pages.shape[2], "head_dim": q.shape[2],
-              "ctx": NB * k_pages.shape[1]}
+              "kv_heads": k_pages.shape[-2], "head_dim": q.shape[2],
+              "ctx": NB * k_pages.shape[-3]}
     c = resolve_kernel_config("paged_attention", shapes, q.dtype,
                               config=config, tuned=tuned,
                               explicit={"num_splits": num_splits})
     splits = max(min(int(c.get("num_splits", 1)), NB), 1)
-    return _pa_jit(q, k_pages, v_pages, block_tables, context_lens, scale,
-                   window, softcap, interpret, bool(hbm), splits,
-                   ctx.kernel_mesh())
+    return _pa_jit(q, k_pages, v_pages, block_tables, context_lens,
+                   jnp.asarray(layer, jnp.int32), scale, window, softcap,
+                   interpret, bool(hbm), splits, ctx.kernel_mesh())
 
 
 @jax.jit

@@ -22,10 +22,16 @@ with the position, ``-1``-entry, window and softcap rules, so the
 MXU work of a grouped product, on an MXU that decode otherwise leaves
 idle, for two full-width matmuls and no transposes.  The online-softmax
 (m, l, acc) recurrence runs row-wise, one row per query head.  Block
-tables and context lengths are scalar-prefetched into SMEM
+tables, context lengths and the layer are scalar-prefetched into SMEM
 (``PrefetchScalarGridSpec``), so page ids and the loop trip count — the
 sequence's own span count — are scalars: short contexts cost few
-iterations regardless of the table width.  q and the outputs enter as
+iterations regardless of the table width.
+
+The pool may be one layer's ``[P, bs, KH, D]`` or the model's stacked
+``[L, P, bs, KH, D]`` with a ``layer`` index: the kernel reads that
+layer's pages where they lie, so a layer scan that carries the whole
+pool hands it over without slicing it.  A one-layer pool is the same
+call on ``pool[None]`` at layer 0.  q and the outputs enter as
 ``[B, H, D]`` blocks of ``(None, H, D)``, whose last two dimensions
 equal the array's, as the TPU's (8, 128) tiling rule asks.
 
@@ -47,18 +53,19 @@ paged *write*).
 
 Two lowerings share one kernel body and one wrapper signature:
 
-* ``paged_attention`` — the in_specs declare the whole page pool as one
-  block per grid cell, and the span copies run VMEM to VMEM.  Exact in
-  interpret mode and fine for CI-sized pools, but it stages the *pool*
-  into VMEM.
+* ``paged_attention`` — the in_specs declare the layer's whole page
+  pool as one block per grid cell, and the span copies run VMEM to
+  VMEM.  Exact in interpret mode and fine for CI-sized pools, but it
+  stages the layer's *pool* into VMEM.
 * ``paged_attention_hbm`` — the HBM-resident lowering: ``k_pages`` /
   ``v_pages`` stay in ``ANY``/HBM memory space and each loop iteration
-  async-copies the span's table-selected pages into a double-buffered
-  VMEM scratch (span ``j+1``'s copies are issued before span ``j`` is
-  consumed), so VMEM holds exactly two K spans + two V spans + the
-  q/acc rows, independent of pool size.  A copy moves one whole
-  ``[bs, KH, D]`` page; span pages past the row's valid pages, and
-  ``-1`` entries, fetch the clipped page, whose positions are masked.
+  async-copies the span's table-selected pages of the layer into a
+  double-buffered VMEM scratch (span ``j+1``'s copies are issued
+  before span ``j`` is consumed), so VMEM holds exactly two K spans +
+  two V spans + the q/acc rows, independent of pool size.  A copy
+  moves one whole ``[bs, KH, D]`` page; span pages past the row's valid
+  pages, and ``-1`` entries, fetch the clipped page, whose positions
+  are masked.
   The pipeline is per split: each split walks its own consecutive ``j``
   range, so the two-slot parity scheme works for any ``num_splits``.
 
@@ -160,13 +167,14 @@ def _row(bt_ref, ctx_ref, n_blocks):
                                         + jnp.minimum(j, n_blocks - 1)]
 
 
-def _pa_span_loop(table, k_pool, v_pool, *, attend, init, span, n_pages,
-                  lo, hi):
+def _pa_span_loop(table, k_pool, v_pool, layer, *, attend, init, span,
+                  n_pages, lo, hi):
     """The double-buffered copy pipeline over spans ``[lo, hi)``: issue
     span ``j+1``'s page copies before waiting on span ``j`` so the
     gather overlaps the compute.  A span is ``span`` whole-page copies
-    each of K and V, all signalling their slot's semaphore; the pool is
-    HBM-resident or VMEM-staged, the copies are the same.  ``j`` runs
+    each of K and V, all signalling their slot's semaphore; the pool
+    ``[L, P, bs, KH, D]`` is HBM-resident or VMEM-staged, the copies are
+    the same: page ``pid`` of ``layer``.  ``j`` runs
     consecutively within the range, so the two-slot parity scheme
     (``slot = j % 2``) holds for any split's ``lo`` — VMEM cost is two K
     + two V spans regardless of how many splits share the sequence.
@@ -174,10 +182,10 @@ def _pa_span_loop(table, k_pool, v_pool, *, attend, init, span, n_pages,
     def body(k_buf, v_buf, k_sem, v_sem):
         def copies(slot, j, i):
             pid = jnp.clip(table(j * span + i), 0, n_pages - 1)
-            return (pltpu.make_async_copy(k_pool.at[pid], k_buf.at[slot, i],
-                                          k_sem.at[slot]),
-                    pltpu.make_async_copy(v_pool.at[pid], v_buf.at[slot, i],
-                                          v_sem.at[slot]))
+            return (pltpu.make_async_copy(k_pool.at[layer, pid],
+                                          k_buf.at[slot, i], k_sem.at[slot]),
+                    pltpu.make_async_copy(v_pool.at[layer, pid],
+                                          v_buf.at[slot, i], v_sem.at[slot]))
 
         def each_page(slot, j, op):
             def one(i, _):
@@ -203,18 +211,20 @@ def _pa_span_loop(table, k_pool, v_pool, *, attend, init, span, n_pages,
 
     return pl.run_scoped(
         body,
-        k_buf=pltpu.VMEM((2, span) + k_pool.shape[1:], k_pool.dtype),
-        v_buf=pltpu.VMEM((2, span) + v_pool.shape[1:], v_pool.dtype),
+        k_buf=pltpu.VMEM((2, span) + k_pool.shape[2:], k_pool.dtype),
+        v_buf=pltpu.VMEM((2, span) + v_pool.shape[2:], v_pool.dtype),
         k_sem=pltpu.SemaphoreType.DMA((2,)),
         v_sem=pltpu.SemaphoreType.DMA((2,)))
 
 
-def _pa_kernel(bt_ref, ctx_ref, q_ref, k_ref, v_ref, *out_refs, n_blocks,
-               num_splits, scale, window, softcap, block_size, n_pages):
+def _pa_kernel(bt_ref, ctx_ref, layer_ref, q_ref, k_ref, v_ref, *out_refs,
+               n_blocks, num_splits, scale, window, softcap, block_size,
+               n_pages, hbm):
     """One row's grid cell — or one ``(b, s)`` split — of either
     lowering, for all ``H`` query heads: walk the cell's span range,
     then write the normalized ``[H, D]`` output (unsplit) or the
-    partial ``(m, l, acc)`` rows (split)."""
+    partial ``(m, l, acc)`` rows (split).  The staged lowering's block
+    already holds only the prefetched layer, at index 0."""
     ctx, table = _row(bt_ref, ctx_ref, n_blocks)
     span = max(1, 128 // block_size)      # table entries per loop step
     n_spans = pl.cdiv(ctx, span * block_size)             # traced trip count
@@ -226,7 +236,8 @@ def _pa_kernel(bt_ref, ctx_ref, q_ref, k_ref, v_ref, *out_refs, n_blocks,
     attend = functools.partial(_attend_span, q, table=table, ctx=ctx,
                                window=window, softcap=softcap,
                                block_size=block_size)
-    m, l, acc = _pa_span_loop(table, k_ref, v_ref, attend=attend,
+    layer = layer_ref[0] if hbm else 0
+    m, l, acc = _pa_span_loop(table, k_ref, v_ref, layer, attend=attend,
                               init=_carry_init(*q.shape), span=span,
                               n_pages=n_pages, lo=lo, hi=hi)
     if num_splits == 1:
@@ -239,17 +250,21 @@ def _pa_kernel(bt_ref, ctx_ref, q_ref, k_ref, v_ref, *out_refs, n_blocks,
         acc_ref[...] = acc
 
 
-def _paged_call(q, k_pages, v_pages, block_tables, context_lens, *, hbm,
-                scale, window, softcap, num_splits, interpret):
+def _paged_call(q, k_pages, v_pages, block_tables, context_lens, layer, *,
+                hbm, scale, window, softcap, num_splits, interpret):
     """Build and run the ``pallas_call`` of either lowering.
 
-    Block tables (flattened) and context lengths are scalar-prefetched
-    into SMEM, so page ids and trip counts are read as scalars.  Every
-    block holds a row's whole ``[H, D]`` (or ``[H, 1]``) — its last two
-    dimensions equal the array's, as the TPU's tiling requires; the
-    batch and split axes are squeezed grid axes."""
+    Block tables (flattened), context lengths and the layer are
+    scalar-prefetched into SMEM, so page ids and trip counts are read as
+    scalars.  Every block holds a row's whole ``[H, D]`` (or ``[H, 1]``)
+    — its last two dimensions equal the array's, as the TPU's tiling
+    requires; the batch and split axes are squeezed grid axes.  A
+    one-layer pool ``[P, bs, KH, D]`` runs as ``[1, P, bs, KH, D]`` at
+    layer 0 (``layer`` is then ignored)."""
+    if k_pages.ndim == 4:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
     B, H, D = q.shape
-    P, bs, KH, _ = k_pages.shape
+    _, P, bs, KH, _ = k_pages.shape
     NB = block_tables.shape[1]
     scale = scale if scale is not None else D ** -0.5
     num_splits = max(int(num_splits), 1)
@@ -257,7 +272,9 @@ def _paged_call(q, k_pages, v_pages, block_tables, context_lens, *, hbm,
     if hbm:
         pool_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
     else:
-        pool_spec = pl.BlockSpec((P, bs, KH, D), lambda *_: (0, 0, 0, 0))
+        # index maps get the grid indices, then the prefetched refs
+        pool_spec = pl.BlockSpec((1, P, bs, KH, D),
+                                 lambda *a: (a[-1][0], 0, 0, 0, 0))
     in_specs = [pl.BlockSpec((None, H, D), lambda b, *_: (b, 0, 0)),
                 pool_spec, pool_spec]
     if num_splits == 1:
@@ -273,28 +290,30 @@ def _paged_call(q, k_pages, v_pages, block_tables, context_lens, *, hbm,
             jax.ShapeDtypeStruct((B, num_splits, H, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, num_splits, H, D), jnp.float32)]
     kernel = functools.partial(
-        _pa_kernel, n_blocks=NB, num_splits=num_splits, scale=scale, window=window,
-        softcap=softcap, block_size=bs, n_pages=P)
+        _pa_kernel, n_blocks=NB, num_splits=num_splits, scale=scale,
+        window=window, softcap=softcap, block_size=bs, n_pages=P, hbm=hbm)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+            num_scalar_prefetch=3, grid=grid, in_specs=in_specs,
             out_specs=out_specs),
         out_shape=out_shape,
         interpret=interpret,
     )(jnp.asarray(block_tables, jnp.int32).reshape(B * NB),
       jnp.asarray(context_lens, jnp.int32).reshape(B),
+      jnp.asarray(layer, jnp.int32).reshape(1),
       q, k_pages, v_pages)
     if num_splits == 1:
         return out
     return _merge_partials(*out, q.dtype)
 
 
-def paged_attention(q, k_pages, v_pages, block_tables, context_lens, *,
-                    scale=None, window=None, softcap=None, num_splits=1,
-                    interpret=False):
-    """q [B,H,D]; k/v_pages [P,bs,KH,D]; block_tables [B,NB] int32 (-1 =
-    unbacked); context_lens [B] int32 -> [B,H,D].
+def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
+                    layer=0, *, scale=None, window=None, softcap=None,
+                    num_splits=1, interpret=False):
+    """q [B,H,D]; k/v_pages [P,bs,KH,D], or the stacked [L,P,bs,KH,D]
+    read at ``layer`` (an int32 scalar, may be traced); block_tables
+    [B,NB] int32 (-1 = unbacked); context_lens [B] int32 -> [B,H,D].
 
     Attention of one new token per sequence over its paged context: the
     query position is ``context_lens - 1`` (causality holds by
@@ -307,21 +326,21 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens, *,
     log-sum-exp merge pass — same outputs up to summation order.
     """
     return _paged_call(q, k_pages, v_pages, block_tables, context_lens,
-                       hbm=False, scale=scale, window=window,
+                       layer, hbm=False, scale=scale, window=window,
                        softcap=softcap, num_splits=num_splits,
                        interpret=interpret)
 
 
-def paged_attention_hbm(q, k_pages, v_pages, block_tables, context_lens, *,
-                        scale=None, window=None, softcap=None, num_splits=1,
-                        interpret=False):
+def paged_attention_hbm(q, k_pages, v_pages, block_tables, context_lens,
+                        layer=0, *, scale=None, window=None, softcap=None,
+                        num_splits=1, interpret=False):
     """``paged_attention`` with the page pool kept in HBM (``ANY`` memory
     space) and per-span double-buffered async copies — the production
     lowering for pools far larger than VMEM.  Same contract and oracle
     (``ref.paged_attention_ref``) as the staged lowering, including the
     ``num_splits`` flash-decoding form."""
     return _paged_call(q, k_pages, v_pages, block_tables, context_lens,
-                       hbm=True, scale=scale, window=window,
+                       layer, hbm=True, scale=scale, window=window,
                        softcap=softcap, num_splits=num_splits,
                        interpret=interpret)
 
@@ -332,8 +351,9 @@ def paged_attention_hbm(q, k_pages, v_pages, block_tables, context_lens, *,
 
 
 def paged_attention_sharded(q, k_pages, v_pages, block_tables, context_lens,
-                            mesh, *, scale=None, window=None, softcap=None,
-                            num_splits=1, hbm=False, interpret=False):
+                            mesh, layer=0, *, scale=None, window=None,
+                            softcap=None, num_splits=1, hbm=False,
+                            interpret=False):
     """Per-shard head slices of the paged kernel over a ``('data',
     'model')`` mesh: query heads and KV heads split over ``'model'``,
     batch rows over ``'data'``, block tables and context lengths
@@ -350,7 +370,10 @@ def paged_attention_sharded(q, k_pages, v_pages, block_tables, context_lens,
     from jax.sharding import PartitionSpec as P
 
     B, H, D = q.shape
-    KH = k_pages.shape[2]
+    if k_pages.ndim == 4:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    KH = k_pages.shape[3]
+    layer = jnp.asarray(layer, jnp.int32)
     scale = scale if scale is not None else D ** -0.5
     kern = paged_attention_hbm if hbm else paged_attention
     call = functools.partial(kern, scale=scale, window=window,
@@ -360,16 +383,17 @@ def paged_attention_sharded(q, k_pages, v_pages, block_tables, context_lens,
     head_ok = m_sz == 1 or (H % m_sz == 0 and KH % m_sz == 0)
     batch_ok = d_sz == 1 or B % d_sz == 0
     if (d_sz * m_sz == 1) or not head_ok:
-        return call(q, k_pages, v_pages, block_tables, context_lens)
+        return call(q, k_pages, v_pages, block_tables, context_lens, layer)
     bax = "data" if (d_sz > 1 and batch_ok) else None
     hax = "model" if m_sz > 1 else None
     return jax.shard_map(
         call, mesh=mesh,
-        in_specs=(P(bax, hax, None),          # q: rows x head slice
-                  P(None, None, hax, None),   # pools: KV-head slice
-                  P(None, None, hax, None),
-                  P(bax, None),               # tables: replicated per shard
-                  P(bax,)),                   # context lengths
+        in_specs=(P(bax, hax, None),            # q: rows x head slice
+                  P(None, None, None, hax, None),   # pools: KV-head slice
+                  P(None, None, None, hax, None),
+                  P(bax, None),                 # tables: replicated per shard
+                  P(bax,),                      # context lengths
+                  P()),                         # the layer
         out_specs=P(bax, hax, None),
         check_vma=False,
-    )(q, k_pages, v_pages, block_tables, context_lens)
+    )(q, k_pages, v_pages, block_tables, context_lens, layer)

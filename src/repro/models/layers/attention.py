@@ -184,55 +184,58 @@ def _project_kv(p, xk, cfg):
     return k, v
 
 
-def _paged_update_gather(cache, k_new, v_new, block_tables, write_pos):
-    """Write ``Sq`` new tokens per row into the paged KV pool through the
-    block table, then gather the logical per-row K/V view for attention.
+def _paged_update_gather(pool, k_new, v_new, block_tables, write_pos, layer):
+    """Write ``Sq`` new tokens per row into one layer of the paged KV
+    pool through the block table, then gather that layer's logical
+    per-row K/V view for attention.
 
-    cache         {'k','v': [n_blocks, bs, KH, hd]} the physical pool
+    pool          {'k','v': [L, n_blocks, bs, KH, hd]} the whole stacked
+                  physical pool (a layer scan's carry)
     k_new, v_new  [B, Sq, KH, hd] projections for this call's tokens
     block_tables  [B, NB] int32 physical block per logical block (-1 =
                   unbacked; positions there are masked)
     write_pos     [B] first write position; may be NEGATIVE (left-padded
                   chunked-prefill calls, or inactive rows at -1) — those
                   token writes scatter out-of-bounds and are dropped
+    layer         int32 scalar (may be traced): the layer written and read
 
-    Returns (new_cache, k [B,L,KH,hd], v, kpos [B,L]) with L = NB*bs; the
+    One scatter at ``[layer, block, slot]`` updates the pool in place and
+    the gather reads ``[layer, page, slot]`` from it: no layer's pages
+    are sliced out of the pool or written back.
+
+    Returns (new_pool, k [B,S,KH,hd], v, kpos [B,S]) with S = NB*bs; the
     gathered view is the pure-jnp CPU reference of the paged decode (the
     Pallas ``kernels.paged_attention`` gathers page-by-page on TPU).
     """
-    ck, cv = cache["k"], cache["v"]
-    nb, bs = ck.shape[0], ck.shape[1]
-    B, Sq, KH, hd = k_new.shape
+    nb, bs = pool["k"].shape[1], pool["k"].shape[2]
+    Sq = k_new.shape[1]
     NB = block_tables.shape[1]
     pos = write_pos[:, None] + jnp.arange(Sq, dtype=jnp.int32)[None]
     blk = jnp.take_along_axis(block_tables,
                               jnp.clip(pos // bs, 0, NB - 1), axis=1)
-    # flat physical slot per new token; invalid -> nb*bs, dropped by the
+    # physical block per new token; invalid -> nb, dropped by the
     # out-of-bounds scatter mode
-    phys = jnp.where((pos >= 0) & (blk >= 0), blk * bs + pos % bs, nb * bs)
-
-    def write(c, n):
-        flat = c.reshape(nb * bs, KH, hd)
-        flat = flat.at[phys.reshape(-1)].set(
-            n.reshape(B * Sq, KH, hd).astype(c.dtype), mode="drop")
-        return flat.reshape(c.shape)
-
-    k_cache, v_cache = write(ck, k_new), write(cv, v_new)
+    blk = jnp.where((pos >= 0) & (blk >= 0), blk, nb)
+    write = lambda c, n: c.at[layer, blk, pos % bs].set(  # noqa: E731
+        n.astype(c.dtype), mode="drop")
+    new_pool = {"k": write(pool["k"], k_new), "v": write(pool["v"], v_new)}
 
     lslot = jnp.arange(NB * bs, dtype=jnp.int32)
-    page = block_tables[:, lslot // bs]                     # [B, L]
-    idx = jnp.where(page >= 0, page * bs + (lslot % bs)[None], 0)
-    written = (page >= 0) & (lslot[None] <= write_pos[:, None] + Sq - 1)
-    k = k_cache.reshape(nb * bs, KH, hd)[idx]
-    v = v_cache.reshape(nb * bs, KH, hd)[idx]
+    page = block_tables[:, lslot // bs]                     # [B, S]
+    backed = page >= 0
+    page = jnp.where(backed, page, 0)
+    slot = jnp.where(backed, (lslot % bs)[None], 0)
+    written = backed & (lslot[None] <= write_pos[:, None] + Sq - 1)
+    k = new_pool["k"][layer, page, slot]
+    v = new_pool["v"][layer, page, slot]
     kpos = jnp.where(written, lslot[None], -1)
-    return {"k": k_cache, "v": v_cache}, k, v, kpos
+    return new_pool, k, v, kpos
 
 
 def attention(p, x, *, cfg, positions, is_global, theta=None,
               memory=None, mem_positions=None,
               cache: Optional[dict] = None, write_pos=None,
-              block_tables=None, pre_output=False, causal=True):
+              block_tables=None, layer=None, pre_output=False, causal=True):
     """Unified attention layer.
 
     x          [B,Sq,D]   layer input (post-norm)
@@ -240,12 +243,14 @@ def attention(p, x, *, cfg, positions, is_global, theta=None,
     is_global  bool/array scalar flag; local layers use cfg.window
     memory     [B,Sm,D]   if set: cross-attention onto encoder memory
     cache      {'k','v' : [B,Smax,KH,hd]} decode/prefill KV cache (self-attn)
-               — or the paged pool [n_blocks,bs,KH,hd] with block_tables
+               — or the whole paged pool [L,n_blocks,bs,KH,hd] with
+               block_tables, of which this call writes and reads ``layer``
     write_pos  [B]        decode: slot to write the new token's K/V
     block_tables [B,NB]   paged decode: per-row physical block ids; the
                cache is then the shared block pool and K/V are gathered
                through the table (``models`` CPU reference of the paged
                path; ``kernels.paged_attention`` is the TPU kernel)
+    layer      int32 scalar (may be traced): the paged pool's layer
     pre_output if True return pre-wo head outputs [B,Sq,H*hd] (hymba fusion)
 
     Returns (out, new_cache).
@@ -284,7 +289,7 @@ def attention(p, x, *, cfg, positions, is_global, theta=None,
         k_new, v_new = _project_kv(p, x, cfg)
         k_new = apply_rope(k_new, sin_q, cos_q)
         new_cache, k, v, kpos = _paged_update_gather(
-            cache, k_new, v_new, block_tables, write_pos)
+            cache, k_new, v_new, block_tables, write_pos, layer)
         k, v = k.astype(cdt), v.astype(cdt)
         causal = True
     else:
@@ -357,7 +362,7 @@ def attention(p, x, *, cfg, positions, is_global, theta=None,
             out_h = kops.paged_attention(
                 q[:, 0], new_cache["k"], new_cache["v"], block_tables,
                 write_pos + 1, scale=scale, window=window,
-                softcap=cfg.attn_softcap, tuned=True)[:, None]
+                softcap=cfg.attn_softcap, tuned=True, layer=layer)[:, None]
     elif use_pallas:
         # TPU hot path: the blocked flash kernel (kernels/flash_attention);
         # ragged sequence tails are padded+masked inside the kernel.

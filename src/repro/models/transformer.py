@@ -233,8 +233,10 @@ def expert_counts(aux) -> dict:
 
 
 def _layer(x, lp, *, cfg, positions, is_global, cache_layer, write_pos, mode,
-           block_tables=None):
-    """Returns (x, new_cache_layer, aux)."""
+           block_tables=None, layer=None):
+    """Returns (x, new_cache_layer, aux).  With ``block_tables`` the
+    cache is the whole paged pool, written and read at ``layer``, and
+    comes back whole."""
     cdt = x.dtype
     x = ctx.constrain(x, "batch", None, None)
     aux = _aux_zeros(cfg, mode)
@@ -269,7 +271,7 @@ def _layer(x, lp, *, cfg, positions, is_global, cache_layer, write_pos, mode,
         a_out, a_cache = attn_mod.attention(
             lp["attn"], h_in, cfg=cfg, positions=positions,
             is_global=is_global, cache=use_cache, write_pos=write_pos,
-            block_tables=block_tables,
+            block_tables=block_tables, layer=layer,
             pre_output=(cfg.family == "hybrid"))
 
     new_cache = {}
@@ -363,7 +365,8 @@ def lm_apply(params, cfg, *, tokens, mode, prefix_embeds=None, cache=None,
     tokens        [B,S] int32 (decode: S==1, or a chunked-prefill chunk)
     prefix_embeds [B,P,D] stub modality embeddings (vlm), prepended
     cache         stacked decode cache (mode == 'decode'); with
-                  block_tables, the stacked PAGED pool [L,n_blocks,bs,...]
+                  block_tables, the stacked PAGED pool [L,n_blocks,bs,...],
+                  carried whole through the layers and updated in place
     write_pos     [B] cache slot for the new tokens (decode); may be
                   negative for left-padded chunked-prefill rows (those
                   writes are dropped by the paged scatter)
@@ -407,44 +410,53 @@ def lm_apply(params, cfg, *, tokens, mode, prefix_embeds=None, cache=None,
     aux_tot = _aux_zeros(cfg, mode)
     max_len = max_len or St
     pre_caches = []
+    # the paged pool is carried whole through every layer, each writing
+    # and reading its own pages in place; a slot cache is sliced per layer
+    pool = cache if block_tables is not None else None
 
     # --- leading unstacked layers (deepseek dense layer 0) -------------------
     for i, lp in enumerate(params["pre_layers"]):
-        cl = (jax.tree.map(lambda c: c[i], cache) if cache is not None else None)
+        if pool is not None:
+            cl = pool
+        else:
+            cl = (jax.tree.map(lambda c: c[i], cache)
+                  if cache is not None else None)
         x, ncl, aux = _layer(x, lp, cfg=cfg, positions=positions,
                              is_global=glob[i], cache_layer=cl,
                              write_pos=write_pos, mode=mode,
-                             block_tables=block_tables)
+                             block_tables=block_tables, layer=i)
         aux_tot = _merge_aux(aux_tot, aux)
-        if mode != "train":
+        if pool is not None:
+            pool = ncl
+        elif mode != "train":
             pre_caches.append(_prefill_pad_cache(ncl, max_len)
                               if mode == "prefill" else ncl)
 
     # --- scanned stack --------------------------------------------------------
     stack = params["layers"]
     glob_stack = glob[n_pre:]
-    cache_stack = (jax.tree.map(lambda c: c[n_pre:], cache)
-                   if cache is not None else None)
 
     def body(carry, xs):
-        x, aux_acc = carry
-        if mode == "decode":
-            lp, g, cl = xs
-        else:
-            lp, g = xs
-            cl = None
+        x, aux_acc, pool = carry
+        lp, g, per_layer = xs
+        if pool is not None:        # the whole paged pool, at this layer
+            cl, layer = pool, per_layer
+        else:                       # this layer's slot cache, or None
+            cl, layer = per_layer, None
         x, ncl, aux = _layer(x, lp, cfg=cfg, positions=positions,
                              is_global=g, cache_layer=cl,
                              write_pos=write_pos, mode=mode,
-                             block_tables=block_tables)
+                             block_tables=block_tables, layer=layer)
         aux_acc = _merge_aux(aux_acc, aux)
+        if pool is not None:
+            return (x, aux_acc, ncl), None
         if mode == "train":
             ys = 0.0
         elif mode == "prefill":
             ys = _prefill_pad_cache(ncl, max_len)
         else:
             ys = ncl
-        return (x, aux_acc), ys
+        return (x, aux_acc, pool), ys
 
     if mode == "train" and remat:
         policy = (jax.checkpoint_policies.save_only_these_names("attn_out")
@@ -452,9 +464,14 @@ def lm_apply(params, cfg, *, tokens, mode, prefix_embeds=None, cache=None,
                   else jax.checkpoint_policies.nothing_saveable)
         body = jax.checkpoint(body, policy=policy)
 
-    xs = ((stack, glob_stack, cache_stack) if mode == "decode"
-          else (stack, glob_stack))
-    (x, aux_tot), ys = jax.lax.scan(body, (x, aux_tot), xs)
+    if pool is not None:
+        per_layer = jnp.arange(n_pre, cfg.n_layers, dtype=jnp.int32)
+    elif mode == "decode":
+        per_layer = jax.tree.map(lambda c: c[n_pre:], cache)
+    else:
+        per_layer = None
+    (x, aux_tot, pool), ys = jax.lax.scan(
+        body, (x, aux_tot, pool), (stack, glob_stack, per_layer))
 
     x = (basic.layernorm if cfg.family == "ssm" else basic.rmsnorm)(
         params["ln_f"], x, cfg.norm_eps)
@@ -465,7 +482,9 @@ def lm_apply(params, cfg, *, tokens, mode, prefix_embeds=None, cache=None,
                            vocab=cfg.vocab_size)
 
     new_cache = None
-    if mode != "train":
+    if pool is not None:
+        new_cache = pool
+    elif mode != "train":
         new_cache = ys
         if pre_caches:
             pre_stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *pre_caches)

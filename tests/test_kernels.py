@@ -208,6 +208,34 @@ def test_paged_attention_hbm_zero_context_and_unbacked_page():
     np.testing.assert_allclose(np.asarray(o2), np.asarray(r2), atol=1e-5)
 
 
+@pytest.mark.parametrize("layer", ["first", "last"])
+@pytest.mark.parametrize("num_splits", [1, 4])
+@pytest.mark.parametrize("hbm", [False, True])
+def test_paged_attention_reads_one_layer_of_a_stacked_pool(hbm, num_splits,
+                                                           layer):
+    """A stacked ``[L, P, bs, KH, D]`` pool read at a traced layer index
+    gives exactly the one-layer call on ``pool[l]``: the kernel's page
+    copies index the layer, nothing slices the pool."""
+    from repro.kernels.paged_attention import (paged_attention,
+                                               paged_attention_hbm)
+    L, bs = 3, 4
+    q, _, _, bt, ctx = _paged_case(3, 4, 2, 16, bs, (1, 7, 18), n_pages=16)
+    bt = bt.at[1, 1].set(-1)                 # an unbacked page in context
+    rng = np.random.default_rng(1)
+    kp, vp = (jnp.asarray(rng.normal(size=(L, 16, bs, 2, 16)), jnp.float32)
+              for _ in range(2))
+    l = 0 if layer == "first" else L - 1
+    kern = paged_attention_hbm if hbm else paged_attention
+    call = lambda k, v, i: kern(q, k, v, bt, ctx, i,  # noqa: E731
+                                num_splits=num_splits, interpret=True)
+    stacked = jax.jit(call)(kp, vp, jnp.int32(l))
+    one = call(kp[l], vp[l], 0)
+    np.testing.assert_array_equal(np.asarray(stacked), np.asarray(one))
+    np.testing.assert_allclose(
+        np.asarray(one), np.asarray(ref.paged_attention_ref(
+            q, kp[l], vp[l], bt, ctx)), atol=1e-5)
+
+
 def test_paged_attention_matches_contiguous_flash_decode():
     """Ground truth: the paged gather over shuffled pages must equal plain
     single-query attention over the contiguous K/V it represents."""

@@ -6,6 +6,7 @@ the paged pool sized strictly below the slot engine's
 ``max_batch x max_len`` rectangle — identical greedy token ids, strictly
 fewer resident KV bytes, leak-free teardown.
 """
+import dataclasses
 import functools
 
 import jax
@@ -19,6 +20,8 @@ except ImportError:          # degrade: property tests skip, the rest run
     from _hypothesis_stub import given, settings, st
 
 from repro.configs import ARCHS, reduced
+from repro.models import transformer
+from repro.models.layers import attention as attn_mod, basic
 from repro.models.zoo import build_model
 from repro.serve import PagedServingEngine, ServingEngine
 from repro.serve.paging import BlockAllocator, remap_table
@@ -232,3 +235,133 @@ def test_decode_chunk_equals_prefill_logits():
                 jnp.asarray([start], jnp.int32), bt)
             filled = end
         assert int(jnp.argmax(logits[0])) == want, (S, C)
+
+
+# ---------------------------------------------------------------------------
+# the layer scan carries the pool and writes each layer's pages in place
+# ---------------------------------------------------------------------------
+
+
+def _one_layer_update_gather(cache, k_new, v_new, block_tables, write_pos,
+                             layer=None):
+    """The per-layer pool update the layer scan made before it carried
+    the whole pool: one layer's ``[n_blocks, bs, KH, hd]`` pages (so no
+    ``layer``), a flat scatter of the new tokens, a flat gather of the
+    logical view."""
+    ck, cv = cache["k"], cache["v"]
+    nb, bs = ck.shape[0], ck.shape[1]
+    B, Sq, KH, hd = k_new.shape
+    NB = block_tables.shape[1]
+    pos = write_pos[:, None] + jnp.arange(Sq, dtype=jnp.int32)[None]
+    blk = jnp.take_along_axis(block_tables,
+                              jnp.clip(pos // bs, 0, NB - 1), axis=1)
+    phys = jnp.where((pos >= 0) & (blk >= 0), blk * bs + pos % bs, nb * bs)
+
+    def write(c, n):
+        flat = c.reshape(nb * bs, KH, hd)
+        flat = flat.at[phys.reshape(-1)].set(
+            n.reshape(B * Sq, KH, hd).astype(c.dtype), mode="drop")
+        return flat.reshape(c.shape)
+
+    k_cache, v_cache = write(ck, k_new), write(cv, v_new)
+    lslot = jnp.arange(NB * bs, dtype=jnp.int32)
+    page = block_tables[:, lslot // bs]
+    idx = jnp.where(page >= 0, page * bs + (lslot % bs)[None], 0)
+    written = (page >= 0) & (lslot[None] <= write_pos[:, None] + Sq - 1)
+    k = k_cache.reshape(nb * bs, KH, hd)[idx]
+    v = v_cache.reshape(nb * bs, KH, hd)[idx]
+    kpos = jnp.where(written, lslot[None], -1)
+    return {"k": k_cache, "v": v_cache}, k, v, kpos
+
+
+def _per_layer_scan(params, cfg, pool, tokens, write_pos, block_tables):
+    """The paged decode as the layer scan ran it before it carried the
+    pool: each layer's ``[n_blocks, bs, KH, hd]`` pages enter the scan
+    as ``xs`` and leave as ``ys``, updated by the per-layer form."""
+    cdt = jnp.dtype(cfg.compute_dtype)
+    x = basic.embed_tokens(params["embed"], tokens, cdt,
+                           scale_by_dim=cfg.scale_embeds)
+    positions = (write_pos[:, None]
+                 + jnp.arange(tokens.shape[1], dtype=jnp.int32)[None])
+
+    def body(x, xs):
+        lp, g, cl = xs
+        x, ncl, _ = transformer._layer(
+            x, lp, cfg=cfg, positions=positions, is_global=g,
+            cache_layer=cl, write_pos=write_pos, mode="decode",
+            block_tables=block_tables)
+        return x, ncl
+
+    x, pool = jax.lax.scan(body, x, (params["layers"], jnp.asarray(
+        cfg.global_layer_mask(), bool), pool))
+    x = basic.rmsnorm(params["ln_f"], x, cfg.norm_eps)[:, -1:]
+    logits = basic.unembed(params["embed"], x, cdt, cfg.logit_softcap,
+                           vocab=cfg.vocab_size)
+    return logits[:, -1], pool
+
+
+def _scan_case(arch, call):
+    """A 3-layer model, a pool of random pages, and one launch: four
+    decode rows (one inactive at -1) or one 256-token chunk that starts
+    left-padded; tables hold -1 entries past each row's pages."""
+    if arch == "dense":
+        cfg = reduced(ARCHS["internlm2-20b"], n_layers=3, vocab_size=128)
+    else:
+        cfg = reduced(ARCHS["olmoe-1b-7b"], n_layers=3, vocab_size=128)
+        cfg = cfg.replace(moe=dataclasses.replace(
+            cfg.moe, n_experts=8, top_k=2, expert_shards=2, expert_shard=1))
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(1))
+    n_blocks, bs, NB = 40, 16, 20
+    pool = model.init_paged_cache(n_blocks, bs)
+    pool = {n: jax.random.normal(jax.random.PRNGKey(i), c.shape
+                                 ).astype(c.dtype)
+            for i, (n, c) in enumerate(sorted(pool.items()))}
+    rng = np.random.default_rng(2)
+    perm = rng.permutation(n_blocks).astype(np.int32)
+    if call == "decode":
+        write_pos = np.asarray([37, -1, 0, 150], np.int32)
+        tokens = rng.integers(0, cfg.vocab_size, (4, 1)).astype(np.int32)
+        tables = np.full((4, NB), -1, np.int32)
+        for b, n in enumerate((3, 2, 1, 10)):
+            tables[b, :n] = perm[:n] if b == 1 else perm[4 * b:4 * b + n]
+    else:
+        write_pos = np.asarray([-40], np.int32)
+        tokens = rng.integers(0, cfg.vocab_size, (1, 256)).astype(np.int32)
+        tables = np.full((1, NB), -1, np.int32)
+        tables[0, :14] = perm[:14]
+    return (cfg, model, params, pool, jnp.asarray(tokens),
+            jnp.asarray(write_pos), jnp.asarray(tables))
+
+
+@pytest.mark.parametrize("call", ["decode", "chunk"])
+@pytest.mark.parametrize("arch", ["dense", "moe"])
+def test_layer_scan_pool_update_matches_per_layer_slices(arch, call,
+                                                         monkeypatch):
+    """The scanned stack carries the whole pool and writes each layer's
+    pages at ``[layer, block, slot]``; the per-layer scan it replaced,
+    each layer's pages sliced out, updated and stacked back, gives the
+    same tokens, the same logits and the same pool, bit for bit: dropped
+    writes (negative positions, ``-1`` entries) included."""
+    cfg, model, params, pool, tokens, write_pos, tables = _scan_case(
+        arch, call)
+    copy = lambda t: jax.tree.map(jnp.copy, t)  # noqa: E731
+    logits, new_pool = jax.jit(model.decode)(params, copy(pool), tokens,
+                                             write_pos, tables)
+    nxt = jax.jit(model.decode_step)(params, copy(pool), tokens, write_pos,
+                                     tables)[0]
+    monkeypatch.setattr(attn_mod, "_paged_update_gather",
+                        _one_layer_update_gather)
+    want_logits, want_pool = jax.jit(
+        functools.partial(_per_layer_scan, cfg=cfg))(
+        params, pool=pool, tokens=tokens, write_pos=write_pos,
+        block_tables=tables)
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want_logits))
+    np.testing.assert_array_equal(np.asarray(nxt),
+                                  np.asarray(jnp.argmax(want_logits, -1)))
+    for n in ("k", "v"):
+        for layer in range(cfg.n_layers):
+            np.testing.assert_array_equal(np.asarray(new_pool[n][layer]),
+                                          np.asarray(want_pool[n][layer]))
+    # the launch wrote pages: two untouched pools would compare equal too
+    assert not np.array_equal(np.asarray(new_pool["k"]), np.asarray(pool["k"]))

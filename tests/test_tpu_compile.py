@@ -84,6 +84,10 @@ def _total_bytes(compiled):
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
+def _pool_bytes(pool):
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pool))
+
+
 def _cell_engine(cell="internlm2-20b.chat"):
     """A cell's engine: its decode batch, pool and table width."""
     path = (Path(__file__).resolve().parents[1] / "bench" / "workloads"
@@ -95,18 +99,22 @@ CELL = _cell_engine()
 
 
 @pytest.mark.parametrize("num_splits", [1, 4])
-@pytest.mark.parametrize("lowering", ["staged", "hbm", "hbm-cell"])
+@pytest.mark.parametrize("lowering",
+                         ["staged", "hbm", "hbm-cell", "hbm-stacked"])
 def test_paged_attention_compiles_for_v5e(one_chip, lowering, num_splits):
     """Both lowerings at internlm2-20b widths (48 heads over 8 KV heads
     of 128, bf16).  The staged form stages the whole pool into VMEM, so
     it gets a small pool; the HBM form gets the smoke's and the
     benchmark cells' pool, which the kernel reads where it lies: no
-    temporary as large as the pool, so no copy or relayout of it."""
+    temporary as large as the pool, so no copy or relayout of it.
+    ``hbm-stacked`` is the cell's pool stacked over the smoke's layers
+    and read at a traced layer index, as the layer scan reads it: no
+    temporary as large as one layer's pages, so nothing slices it."""
     from repro.kernels.paged_attention import (paged_attention,
                                                paged_attention_hbm)
     cfg = get_config(SMOKE.ARCH)
     H, KH, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    if lowering == "hbm-cell":
+    if lowering.startswith("hbm-"):
         B, bs, P = CELL["max_batch"], CELL["block_size"], CELL["n_blocks"]
         NB = -(-CELL["max_len"] // bs)
     else:
@@ -114,32 +122,42 @@ def test_paged_attention_compiles_for_v5e(one_chip, lowering, num_splits):
         NB = -(-SMOKE.MAX_LEN // bs)
         P = B * NB if lowering == "hbm" else 64
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
-    pool = S((P, bs, KH, D), jnp.bfloat16)
+    layers = (SMOKE.KEEP_LAYERS,) if lowering == "hbm-stacked" else ()
+    pool = S(layers + (P, bs, KH, D), jnp.bfloat16)
     kern = paged_attention if lowering == "staged" else paged_attention_hbm
     compiled = jax.jit(lambda *a: kern(*a, num_splits=num_splits)).lower(
         S((B, H, D), jnp.bfloat16), pool, pool, S((B, NB), jnp.int32),
-        S((B,), jnp.int32)).compile()
+        S((B,), jnp.int32), S((), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     if lowering != "staged":
-        pool_bytes = P * bs * KH * D * 2
-        assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+        layer_bytes = P * bs * KH * D * 2
+        assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
 
 
 def test_paged_decode_step_compiles_for_v5e_and_fits(one_chip, on_tpu):
     """The engine's fused, donated decode step at the smoke's depth: the
     paged kernel is in its HLO (not the jnp gather), named after its
     jitted wrapper and under the ``paged_attention`` scope, and the
-    program fits one chip's 16 GiB."""
+    program fits one chip's 16 GiB.  One pool, not two: the output pool
+    aliases the donated input, and grown to the chat cell's blocks the
+    pool adds less than one layer's pages to the temporaries (the bf16
+    copies of the f32 weights make up the rest of them)."""
     model = _smoke_model()
     B, NB = SMOKE.N_REQUESTS, -(-SMOKE.MAX_LEN // SMOKE.BLOCK)
     params = _placed(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
                      one_chip)
-    pool = _placed(jax.eval_shape(
-        lambda: model.init_paged_cache(B * NB, SMOKE.BLOCK)), one_chip)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
-    compiled = jax.jit(paged_decode_fn(model.decode_step),
-                       donate_argnums=(1,)).lower(
-        params, pool, i32(B), i32(B), i32(B, NB)).compile()
+    temps = []
+    for n_blocks in (B * NB, CELL["n_blocks"]):
+        pool = _placed(jax.eval_shape(
+            lambda: model.init_paged_cache(n_blocks, SMOKE.BLOCK)), one_chip)
+        compiled = jax.jit(paged_decode_fn(model.decode_step),
+                           donate_argnums=(1,)).lower(
+            params, pool, i32(B), i32(B), i32(B, NB)).compile()
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= _pool_bytes(pool)
+        temps.append(mem.temp_size_in_bytes)
+    assert temps[1] - temps[0] < _pool_bytes(pool) // model.cfg.n_layers
     kernels = [ln for ln in compiled.as_text().splitlines()
                if 'custom_call_target="tpu_custom_call"' in ln]
     assert kernels
@@ -184,7 +202,9 @@ def test_olmoe_steps_compile_for_v5e_and_fit(one_chip, on_tpu, program):
     """OLMoE-1B-7B as the ``olmoe-1b-7b.chat4k`` cell runs it: 4 layers,
     16 of 64 experts held, the cell's pool, its 64 decode rows or one
     256-token chunk.  The expert layer's grouped matmul lowers to the
-    TPU's grouped-matmul kernels, and each program fits one chip."""
+    TPU's grouped-matmul kernels, and each program fits one chip.  One
+    pool, not two: the output pool aliases the donated input, and no
+    temporary is as large as one layer's pages."""
     cell = _cell_engine("olmoe-1b-7b.chat4k")
     base = get_config("olmoe-1b-7b")
     cfg = dataclasses.replace(
@@ -214,3 +234,6 @@ def test_olmoe_steps_compile_for_v5e_and_fit(one_chip, on_tpu, program):
     if program == "decode":
         assert "%_pa_jit" in text
     assert _total_bytes(compiled) <= V5E_HBM_BYTES
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < _pool_bytes(pool) // cfg.n_layers
+    assert mem.alias_size_in_bytes >= _pool_bytes(pool)
